@@ -22,7 +22,7 @@ The JSON also records the kernel-plane coverage of the engine rows — which
 round phases run as fused Pallas kernels under the resolved ``kernel_mode``
 (``repro.kernels.fused_phase_coverage``), the ``padded_flop_frac``-style
 column for the kernel plane.  On CPU ``auto`` resolves to ``xla`` and the
-fraction is 0.0; on TPU/GPU the same rows report full fused coverage.
+fraction is 0.0; on a TPU the same rows report full fused coverage.
 
   PYTHONPATH=src python -m benchmarks.run --only engine --emit-json
 """

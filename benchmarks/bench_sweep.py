@@ -18,12 +18,10 @@ driven five ways:
                           column shows the fraction of each plan's compute
                           volume that is padding),
   * ``sharded``         — the fabric's ``shard_map`` path over the mesh
-                          ``data`` axis (measured in a 4-host-device
-                          subprocess via ``--xla_force_host_platform_
-                          device_count``; the vmap path is re-measured
-                          there so the two are compared on equal devices;
-                          single-bucket, since 1-2-point buckets cannot
-                          divide 4 devices).
+                          ``data`` axis of this process's own devices,
+                          measured only where there are several and the
+                          grid divides them (single-bucket, since 1-2-point
+                          buckets cannot divide 4 devices).
 
 Timings are best-of-``REPS`` after a warm-up run (jit caches hot), like
 ``bench_engine``.  The grid is intentionally small (T=10, 1 local step) so
@@ -39,9 +37,8 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import os
-import subprocess
-import sys
+
+import jax
 
 from repro.configs.bhfl_cnn import REDUCED
 
@@ -50,9 +47,6 @@ from .common import Csv, best_of
 T_ROUNDS = 10
 KW = dict(n_train=1500, n_test=300, steps_per_epoch=1, normalize=True)
 REPS = 2
-N_CHILD_DEVICES = 4
-_CHILD_ENV = "BENCH_SWEEP_CHILD"
-_CHILD_MARK = "BENCH_SWEEP_CHILD_JSON:"
 
 # a shape-changing grid: every point has a different topology/round count
 OVERRIDES = [
@@ -83,47 +77,7 @@ def _padding_stats(**sweep_kw) -> dict:
                       **KW).padding_stats()
 
 
-def _child_main() -> None:
-    """Runs inside the forced-4-host-device subprocess."""
-    import jax
-    # single-bucket: forced shard needs the whole 4-point grid in one
-    # stack (auto buckets of 1-2 points cannot divide 4 devices)
-    t_vmap = _measure("vmap", max_buckets=1)
-    t_shard = _measure("shard", max_buckets=1)
-    print(_CHILD_MARK + json.dumps({
-        "devices": len(jax.devices()),
-        "vmap_seconds": t_vmap,
-        "sharded_seconds": t_shard,
-    }))
-
-
-def _spawn_child() -> dict | None:
-    env = dict(os.environ)
-    env[_CHILD_ENV] = "1"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
-                        f"platform_device_count={N_CHILD_DEVICES}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_sweep"],
-            capture_output=True, text=True, env=env, timeout=1200)
-    except subprocess.TimeoutExpired:
-        sys.stderr.write("# bench_sweep: 4-device child timed out; "
-                         "emitting single-device numbers only\n")
-        return None
-    for line in proc.stdout.splitlines():
-        if line.startswith(_CHILD_MARK):
-            return json.loads(line[len(_CHILD_MARK):])
-    sys.stderr.write(proc.stdout + proc.stderr)
-    return None
-
-
 def main(emit_json: bool = True) -> dict:
-    if os.environ.get(_CHILD_ENV) == "1":
-        _child_main()
-        return {}
-
     from repro.fl import BHFLSimulator
 
     csv = Csv("bench_sweep")
@@ -154,17 +108,17 @@ def main(emit_json: bool = True) -> dict:
     csv.row("bucketed", 1, f"{t_bucketed:.2f}",
             f"{n_pts / t_bucketed:.2f}", f"{frac_bucketed:.3f}")
 
-    child = _spawn_child()
-    if child is not None:
-        csv.row("vmap", child["devices"], f"{child['vmap_seconds']:.2f}",
-                f"{n_pts / child['vmap_seconds']:.2f}",
-                f"{frac_single:.3f}")
-        csv.row("sharded", child["devices"],
-                f"{child['sharded_seconds']:.2f}",
-                f"{n_pts / child['sharded_seconds']:.2f}",
-                f"{frac_single:.3f}")
+    n_dev = len(jax.devices())
+    t_shard = None
+    if n_dev > 1 and n_pts % n_dev == 0:
+        t_shard = _measure("shard", max_buckets=1)
+        csv.row("sharded", n_dev, f"{t_shard:.2f}",
+                f"{n_pts / t_shard:.2f}", f"{frac_single:.3f}")
 
     out = {
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "devices": n_dev,
         "setting": "REDUCED",
         "grid": OVERRIDES,
         "t_global_rounds": T_ROUNDS,
@@ -181,17 +135,11 @@ def main(emit_json: bool = True) -> dict:
         "single_bucket_padded_flop_frac": round(frac_single, 4),
         "bucketed_padded_flop_frac": round(frac_bucketed, 4),
     }
-    if child is not None:
+    if t_shard is not None:
         out.update({
-            "child_devices": child["devices"],
-            "vmap_points_per_sec_4dev": round(
-                n_pts / child["vmap_seconds"], 3),
-            "sharded_points_per_sec_4dev": round(
-                n_pts / child["sharded_seconds"], 3),
-            "sharded_speedup_vs_legacy": round(
-                t_legacy / child["sharded_seconds"], 2),
-            "sharded_speedup_vs_vmap_4dev": round(
-                child["vmap_seconds"] / child["sharded_seconds"], 2),
+            "sharded_points_per_sec": round(n_pts / t_shard, 3),
+            "sharded_speedup_vs_legacy": round(t_legacy / t_shard, 2),
+            "sharded_speedup_vs_vmap": round(t_vmap / t_shard, 2),
         })
     if emit_json:
         with open("BENCH_sweep.json", "w") as f:
@@ -201,7 +149,7 @@ def main(emit_json: bool = True) -> dict:
               f"{out['bucket_count']} programs cut padding "
               f"{frac_single:.0%} -> {frac_bucketed:.0%}"
               + (f", sharded {out['sharded_speedup_vs_legacy']}x"
-                 if child is not None else "") + ")")
+                 if t_shard is not None else "") + ")")
     csv.done()
     return out
 

@@ -14,10 +14,10 @@ Two sections:
       - ``coef_agg_pair``  — the generalized coefficient aggregate (pair
         form: the delayed-gradient fill + weighted mean in one pass).
 
-    On this CPU container the kernels run through the Pallas *interpreter*
-    (``fused_backend`` records which), so their wall time is NOT the TPU
-    figure of merit — the HBM model is; on TPU/GPU the same harness times
-    the compiled ``pallas_call``.
+    The kernels take the default interpret policy
+    (``dispatch.default_interpret``): compiled on a TPU, the Pallas
+    *interpreter* on a CPU (``fused_backend`` records which), where their
+    wall time is not a device figure of merit.
   * **engine** — rounds/sec of the same REDUCED deployment as
     ``bench_engine`` with the kernel plane on (``kernel_mode="auto"``) vs
     forced off (``"xla"``), reps interleaved.  On CPU "auto" resolves to
@@ -43,7 +43,8 @@ import jax.numpy as jnp
 
 from repro.configs.bhfl_cnn import REDUCED
 from repro.core import hieavg
-from repro.kernels import fused_phase_coverage, resolve_kernel_mode
+from repro.kernels import (default_interpret, fused_phase_coverage,
+                           resolve_kernel_mode)
 from repro.kernels import ops, ref
 
 from .bench_engine import kernel_plane_record
@@ -155,8 +156,7 @@ def _micro_rows(csv: Csv) -> list[dict]:
     w3 = jax.random.normal(ks[1], (3, 3, cin, cout)) * 0.1
     bb = jax.random.normal(ks[2], (cout,)) * 0.1
     xla_conv = jax.jit(ref.conv3x3_bias_relu_ref)
-    fused_conv = jax.jit(lambda x, w, b: ops.conv3x3_bias_relu(
-        x, w, b, interpret=True))
+    fused_conv = jax.jit(ops.conv3x3_bias_relu)
     xla_ms, fused_ms = _pair_ms(lambda: xla_conv(x, w3, bb),
                                 lambda: fused_conv(x, w3, bb))
     ok = bool(jnp.allclose(xla_conv(x, w3, bb), fused_conv(x, w3, bb),
@@ -174,8 +174,7 @@ def _micro_rows(csv: Csv) -> list[dict]:
     bias = jax.random.normal(ks[2], (c,)) * 0.05
     labels = jax.random.randint(ks[3], (m,), 0, c)
     xla_eval = jax.jit(ref.eval_head_ref)
-    fused_eval = jax.jit(lambda fe, w, b, y: ops.eval_head(
-        fe, w, b, y, interpret=True))
+    fused_eval = jax.jit(ops.eval_head)
     xla_ms, fused_ms = _pair_ms(
         lambda: xla_eval(feats, wmat, bias, labels),
         lambda: fused_eval(feats, wmat, bias, labels))
@@ -194,8 +193,7 @@ def _micro_rows(csv: Csv) -> list[dict]:
     msk = (jax.random.uniform(ks[3], (n,)) > 0.3).astype(jnp.float32)
     ca, cb = coef * msk, coef * (1.0 - msk)
     xla_pair = jax.jit(ref.coef_agg_pair_ref)
-    fused_pair = jax.jit(lambda w, a, ca, cb: ops.coef_agg_pair(
-        w, a, ca, cb, interpret=True))
+    fused_pair = jax.jit(ops.coef_agg_pair)
     xla_ms, fused_ms = _pair_ms(lambda: xla_pair(w, aux, ca, cb),
                                 lambda: fused_pair(w, aux, ca, cb))
     ok = bool(jnp.allclose(xla_pair(w, aux, ca, cb),
@@ -246,7 +244,7 @@ def main(emit_json: bool = False) -> dict:
 
     out = {
         "backend": jax.default_backend(),
-        "fused_backend": "interpret" if auto_mode == "xla" else "pallas",
+        "fused_backend": "interpret" if default_interpret() else "pallas",
         "auto_resolves_to": auto_mode,
         "micro": micro,
         "kernel_plane": kp,

@@ -11,6 +11,8 @@ import argparse
 import functools
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import (ablations, bench_engine, bench_faults, bench_latency,
                bench_population, bench_sweep, fig2_convergence, fig3_sweeps,
                fig4_heterogeneity, fig56_single_layer, fig7_latency,
@@ -43,6 +45,7 @@ def main() -> None:
                     help="seconds-scale budget (latency/faults suites; used "
                          "by the bench-emission smoke test)")
     args = ap.parse_args()
+    use_compile_cache()
     names = args.only.split(",") if args.only else list(SUITES)
     suites = dict(SUITES)
     suites["engine"] = functools.partial(bench_engine.main,

@@ -30,7 +30,7 @@ compiles an ENTIRE run into one program:
   * the hot path (warm HieAvg aggregation at both hierarchy layers, the
     train-step SGD update) routes through the *kernel plane*
     (``repro.kernels.dispatch``): a static ``kernel_mode`` knob selects
-    the fused Pallas kernels on TPU/GPU, the pure-XLA reference on CPU
+    the fused Pallas kernels on TPU, the pure-XLA reference on CPU
     ("auto"), or the Pallas interpreter for validation — and the
     donating entries (``run_engine_donated``; ``split_inputs`` /
     ``SHARED_DATA_FIELDS``) hand the per-run input planes to the
@@ -66,7 +66,7 @@ from repro.core import straggler as strag
 from repro.data import partition
 from repro.fl import faults as _faults
 from repro.kernels import dispatch as kernel_dispatch
-from repro.models import (cnn_accuracy_fast, cnn_loss, cnn_loss_fast,
+from repro.models import (cnn_correct_fast, cnn_loss, cnn_loss_fast,
                           init_from_specs)
 from repro.optim import paper_lr
 
@@ -126,6 +126,32 @@ def train_epoch_body(params: PyTree, images: jnp.ndarray,
     params, losses = jax.lax.scan(step, params, (images, labels, step_ok))
     n_ok = jnp.maximum(jnp.sum(step_ok), 1.0)
     return params, jnp.sum(losses * step_ok[:, None], axis=0) / n_ok
+
+
+#: Test images per eval chunk.  The im2col eval forward holds a 9x
+#: activation buffer, so the engine walks the test set in chunks of this
+#: many rows, not all at once: at the paper's widths a whole 1000-image
+#: test set, times a sweep's points, outgrows a 16 GB chip.
+EVAL_CHUNK = 250
+
+
+def eval_accuracy(params: PyTree, test_x: jnp.ndarray, test_y: jnp.ndarray,
+                  kernel_mode: str = "xla") -> jnp.ndarray:
+    """Test-set accuracy of one model, ``EVAL_CHUNK`` rows at a time.
+
+    The tail chunk is padded with label -1, which never counts, and the
+    exact integer count over the whole set is divided once, so the value
+    equals ``cnn_accuracy_fast`` on the whole set.
+    """
+    n = test_y.shape[0]
+    c = min(n, EVAL_CHUNK)
+    pad = (-n) % c
+    xs = jnp.pad(test_x, ((0, pad),) + ((0, 0),) * (test_x.ndim - 1))
+    ys = jnp.pad(test_y, (0, pad), constant_values=-1)
+    counts = jax.lax.map(
+        lambda xy: cnn_correct_fast(params, *xy, kernel_mode=kernel_mode),
+        (xs.reshape((-1, c) + xs.shape[1:]), ys.reshape(-1, c)))
+    return jnp.sum(counts).astype(jnp.float32) / n
 
 
 # jitted legacy-exact epoch (shifted-sum conv), used by run_legacy
@@ -549,6 +575,25 @@ def init_engine_carry(inp: EngineInputs, history_dtype=None) -> tuple:
             jnp.float32(0.0))                 # cumulative consensus J
 
 
+def _vary_like(tree: PyTree, like: PyTree) -> PyTree:
+    """Mark ``tree``'s leaves varying over every manual mesh axis that any
+    leaf of ``like`` varies over.
+
+    Under ``jax.shard_map`` a scan carry must enter with the varying axes
+    it leaves with.  The round-zero carry is built from the replicated
+    data plane and constants, while the first round already mixes in the
+    sharded per-point planes, so the carry is cast up front.  Outside
+    ``shard_map`` no axis varies and this is the identity.
+    """
+    axes = kernel_dispatch.varying_axes(like)
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree)
+
+
 #: ``EngineInputs`` fields with a leading global-round (T) axis — what
 #: ``slice_rounds`` cuts per chunk for resumable execution.
 ROUND_FIELDS = ("batch_idx", "dev_masks", "edge_masks", "lr", "dev_time",
@@ -634,7 +679,7 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
     edge/global aggregations, the cold-boot means, the FedAvg and
     delayed-gradient aggregates (the "switched" set), and the post-scan
     eval head.  ``"auto"`` resolves to the fused Pallas kernels on
-    TPU/GPU and the pure-XLA reference on CPU (zero overhead);
+    TPU and the pure-XLA reference on CPU (zero overhead);
     ``"interpret"`` forces the Pallas interpreter (the CPU validation
     path the parity tests pin); ``"xla"`` forces the reference.  Only
     the legacy ``t_fedavg``/``d_fedavg`` baselines and the tiny history
@@ -707,7 +752,8 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
             if aggregator in ("hieavg", "switched"):
                 ehist = jax.lax.cond(
                     r == 0,
-                    lambda h: hieavg.init_history_batched(ws, history_dtype),
+                    lambda h: _vary_like(
+                        hieavg.init_history_batched(ws, history_dtype), h),
                     lambda h: h, ehist)
 
                 def cold(w, m, h):
@@ -782,7 +828,8 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
         if aggregator in ("hieavg", "switched"):
             ghist = jax.lax.cond(
                 t == 1,
-                lambda h: hieavg.init_history(edge_models, history_dtype),
+                lambda h: _vary_like(
+                    hieavg.init_history(edge_models, history_dtype), h),
                 lambda h: h, ghist)
             pw = inp.j_arr / jnp.sum(inp.j_arr)
 
@@ -864,22 +911,16 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
     # validity conditions are chunk-invariant
     if carry0 is None:
         carry0 = init_engine_carry(inp, history_dtype)
+    carry0 = _vary_like(carry0, inp)
     t0 = jnp.int32(0) if t_start is None else t_start
     xs = (t0 + jnp.arange(1, T + 1), inp.batch_idx, inp.dev_masks,
           inp.edge_masks, inp.lr, inp.dev_time, inp.cons_time,
           inp.cons_energy, inp.cohort_change)
     final_carry, (globals_per_round, losses, deltas, clocks, energies) = \
         jax.lax.scan(global_round, carry0, xs)
-    # test-set eval over the T round snapshots, outside the training scan.
-    # lax.map (not vmap): one whole-test-set batched matmul per round with
-    # round-at-a-time peak memory — vmapping all T rounds through the 9x
-    # im2col intermediate is O(T * n_test * H * W * 9c) and OOMs at the
-    # paper's DEFAULT sizes.
-    test_x = inp.test_x[inp.seed_idx]
-    test_y = inp.test_y[inp.seed_idx]
     accs = jax.lax.map(
-        lambda w: cnn_accuracy_fast(w, test_x, test_y,
-                                    kernel_mode=kernel_mode),
+        lambda w: eval_accuracy(w, inp.test_x[inp.seed_idx],
+                                inp.test_y[inp.seed_idx], kernel_mode),
         globals_per_round)
     if with_carry:
         return (accs, losses, deltas, clocks, energies), final_carry

@@ -134,7 +134,7 @@ class BHFLSimulator:
 
         ``kernel_mode``: the kernel-plane backend knob (engine path only,
         like ``history_dtype``) — ``"auto"`` runs the fused Pallas
-        aggregation/SGD kernels on TPU/GPU and the pure-XLA reference on
+        aggregation/SGD kernels on TPU and the pure-XLA reference on
         CPU; ``"interpret"``/``"pallas"``/``"xla"`` force a path.  See
         ``repro.kernels.dispatch``.
 

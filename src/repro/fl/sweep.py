@@ -506,7 +506,7 @@ def plan_sweep(setting: BHFLSetting, seeds=(0,), *,
 
     ``kernel_mode`` is the kernel-plane backend knob (like
     ``history_dtype``): resolved here (``"auto"`` → fused Pallas kernels
-    on TPU/GPU, pure-XLA reference on CPU) and baked into the plan so the
+    on TPU, pure-XLA reference on CPU) and baked into the plan so the
     cached runners key on the concrete mode.
 
     Each override may name its own ``"aggregation"``; see ``run_sweep``.
@@ -653,44 +653,62 @@ def _engine_runner(aggregator: str, normalize: bool, history_dtype,
     return runner
 
 
+def _map_points(runner, point_batch: Optional[int]):
+    """``runner(hot, shared)`` over the stacked point axis of ``hot``: all
+    points in one vmap, or ``point_batch`` points at a time (``lax.map``),
+    so the call's working set is that many points' rather than the
+    bucket's.  At the paper's widths one point needs about 6.4 GB of a
+    TPU v5e's 16 GB, so a bucket of several points must walk them."""
+    if point_batch is None:
+        return jax.vmap(runner, in_axes=(0, None))
+
+    def mapped(hot, shared):
+        return jax.lax.map(lambda h: runner(h, shared), hot,
+                           batch_size=point_batch)
+
+    return mapped
+
+
 @functools.lru_cache(maxsize=None)
 def _vmap_runner(aggregator: str, normalize: bool, history_dtype,
-                 kernel_mode: str, donate: bool):
+                 kernel_mode: str, donate: bool,
+                 point_batch: Optional[int] = None):
     """jit(vmap(run_engine)) over the stacked point axis — cached like
     ``_sharded_runner``.  ``donate=True`` hands the hot (stacked) input
     dict to XLA for buffer reuse: a big bucketed grid does not hold the
     caller's copy of the stacked planes alive next to the running
-    program's working set.  The shared data plane is never donated."""
-    fn = jax.vmap(_engine_runner(aggregator, normalize, history_dtype,
-                                 kernel_mode), in_axes=(0, None))
+    program's working set.  The shared data plane is never donated.
+    ``point_batch`` as in ``_map_points``."""
+    fn = _map_points(_engine_runner(aggregator, normalize, history_dtype,
+                                    kernel_mode), point_batch)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
 def _sharded_runner(aggregator: str, normalize: bool, history_dtype,
-                    mesh, spec, kernel_mode: str, donate: bool):
+                    mesh, spec, kernel_mode: str, donate: bool,
+                    point_batch: Optional[int] = None):
     """jit(shard_map(vmap(run_engine))) — cached so repeated sweeps with
     the same static config reuse the compiled executable instead of paying
     a fresh trace + compile per call (jit caches by callable identity; a
     multi-bucket plan compiles one program per bucket *shape* under the
     same cached callable).  ``spec`` shards every hot (stacked) leaf over
     the mesh point axis; the shared data plane is replicated
-    (``sweep_data_spec``).  ``donate`` as in ``_vmap_runner``."""
-    from jax.experimental.shard_map import shard_map
-
-    inner = jax.vmap(_engine_runner(aggregator, normalize, history_dtype,
-                                    kernel_mode), in_axes=(0, None))
-    # shard_map has no replication rule for pallas_call, so the
-    # fused-kernel modes cannot lower with the checker on; keep it for
-    # the pure-XLA mode, where it still guards the replicated data plane
-    sharded = shard_map(inner, mesh=mesh,
-                        in_specs=(spec, sweep_data_spec()),
-                        out_specs=spec, check_rep=(kernel_mode == "xla"))
+    (``sweep_data_spec``).  ``donate`` and ``point_batch`` (per device)
+    as in ``_vmap_runner``."""
+    inner = _map_points(_engine_runner(aggregator, normalize, history_dtype,
+                                       kernel_mode), point_batch)
+    # the Pallas interpreter evaluates kernel bodies without varying-axis
+    # types, so the checker can stay on for every mode but that one
+    sharded = jax.shard_map(inner, mesh=mesh,
+                            in_specs=(spec, sweep_data_spec()),
+                            out_specs=spec,
+                            check_vma=(kernel_mode != "interpret"))
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
 def execute_plan(plan: SweepPlan, *, mesh=None, placement: str = "auto",
-                 donate: bool = True
+                 donate: bool = True, point_batch: Optional[int] = None
                  ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray,
                             np.ndarray]:
     """Run a plan's buckets — one compiled call each — and merge outputs.
@@ -716,9 +734,17 @@ def execute_plan(plan: SweepPlan, *, mesh=None, placement: str = "auto",
     a donated execute the plan's bucket inputs are CONSUMED — re-running
     the same ``SweepPlan`` object requires ``donate=False`` (or a fresh
     plan; ``run_sweep`` re-plans per call either way).
+
+    ``point_batch`` (default None: all of a bucket's points, or of a
+    device's share of them, in one vmap): run them that many at a time
+    inside the bucket's one compiled call, bounding its device memory to
+    that many points' working set.  Results agree with the all-at-once
+    vmap to float rounding.
     """
     if placement not in ("auto", "vmap", "shard"):
         raise ValueError(f"unknown placement {placement!r}")
+    if point_batch is not None and point_batch < 1:
+        raise ValueError(f"point_batch must be >= 1, got {point_batch}")
     if placement != "vmap" and mesh is None:
         mesh = make_sweep_mesh()
 
@@ -760,11 +786,12 @@ def execute_plan(plan: SweepPlan, *, mesh=None, placement: str = "auto",
             if spec == PartitionSpec():
                 outs = _vmap_runner(plan.aggregator, plan.normalize,
                                     plan.history_dtype, plan.kernel_mode,
-                                    donate)(hot, shared)
+                                    donate, point_batch)(hot, shared)
             else:
                 outs = _sharded_runner(plan.aggregator, plan.normalize,
                                        plan.history_dtype, mesh, spec,
-                                       plan.kernel_mode, donate)(hot, shared)
+                                       plan.kernel_mode, donate,
+                                       point_batch)(hot, shared)
         if donate:
             # the compiled call has consumed the stacked planes: drop the
             # plan's reference so it stops pinning the caller-side copy
@@ -789,13 +816,15 @@ def execute_plan(plan: SweepPlan, *, mesh=None, placement: str = "auto",
 
 
 def run_plan(plan: SweepPlan, *, mesh=None, placement: str = "auto",
-             donate: bool = True) -> SweepResult:
+             donate: bool = True, point_batch: Optional[int] = None
+             ) -> SweepResult:
     """Execute a prepared plan and package a ``SweepResult`` — lets callers
     inspect/log the bucket plan (``plan.describe()``) before running it.
-    ``donate`` as in ``execute_plan`` (donated bucket inputs are consumed
-    — pass False to keep the plan re-runnable)."""
+    ``donate`` and ``point_batch`` as in ``execute_plan`` (donated bucket
+    inputs are consumed — pass False to keep the plan re-runnable)."""
     accs, losses, deltas, clocks, energies = execute_plan(
-        plan, mesh=mesh, placement=placement, donate=donate)
+        plan, mesh=mesh, placement=placement, donate=donate,
+        point_batch=point_batch)
     return SweepResult(
         points=plan.points,
         accuracy=accs, loss=losses, grad_norm=deltas, sim_clock=clocks,
@@ -815,6 +844,7 @@ def run_sweep(setting: BHFLSetting, seeds=(0,), *,
               mesh=None, placement: str = "auto",
               max_buckets: int = 4, bucket_waste: float = 1.25,
               bucket_cost: str = "measured",
+              point_batch: Optional[int] = None,
               **sim_kw) -> SweepResult:
     """Grids (including topology/round grids) as a few compiled sharded
     calls — one per shape bucket.
@@ -835,6 +865,9 @@ def run_sweep(setting: BHFLSetting, seeds=(0,), *,
     mixing ``SWITCHABLE_AGGREGATORS`` compiles ONE traced-``"switched"``
     program selected per point by a batched scalar — e.g. HieAvg vs
     delayed-gradient in a single padded shard_map call.
+
+    ``point_batch`` bounds how many points one compiled call holds at once
+    (see ``execute_plan``); at the paper's widths a TPU v5e needs 1.
     """
     plan = plan_sweep(setting, seeds, overrides=overrides,
                       aggregator=aggregator,
@@ -844,4 +877,5 @@ def run_sweep(setting: BHFLSetting, seeds=(0,), *,
                       max_buckets=max_buckets,
                       bucket_waste=bucket_waste, bucket_cost=bucket_cost,
                       **sim_kw)
-    return run_plan(plan, mesh=mesh, placement=placement)
+    return run_plan(plan, mesh=mesh, placement=placement,
+                    point_batch=point_batch)

@@ -21,7 +21,7 @@ defines its semantics contract (tests sweep shapes/dtypes against it):
 points matching the engine's dense ``[N, J, ...]`` + validity-mask
 conventions); ``dispatch.py`` is the backend policy — the
 ``kernel_mode = "auto" | "pallas" | "interpret" | "xla"`` knob that routes
-the engine's hot path to the compiled kernel on TPU/GPU, the pure-XLA
+the engine's hot path to the compiled kernel on TPU, the pure-XLA
 reference on CPU, or the Pallas interpreter for validation.  With the
 conv/eval/cold-boot kernels the fused modes now cover every heavy phase
 of the engine round (``dispatch.ROUND_PHASES``).  See

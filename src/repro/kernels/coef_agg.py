@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .dispatch import default_interpret
+from .dispatch import default_interpret, out_struct
 
 TILE = 2048
 
@@ -78,7 +78,7 @@ def coef_agg(w: jnp.ndarray, coef: jnp.ndarray,
             pl.BlockSpec((1, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, lp), jnp.float32),
+        out_shape=out_struct((1, lp), jnp.float32, w, cvec),
         interpret=interpret,
     )(w, cvec)
     return agg[0, :l]
@@ -107,7 +107,7 @@ def coef_agg_pair(w: jnp.ndarray, aux: jnp.ndarray, ca: jnp.ndarray,
             pl.BlockSpec((2, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, lp), jnp.float32),
+        out_shape=out_struct((1, lp), jnp.float32, w, aux, cvec),
         interpret=interpret,
     )(w, aux, cvec)
     return agg[0, :l]

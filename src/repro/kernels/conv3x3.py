@@ -9,11 +9,11 @@ epilogue into the matmul tile: each program instance holds one
 against the full (small) weight matrix on the MXU with f32 accumulation,
 and applies bias + ReLU before the tile ever leaves VMEM.
 
-The im2col patch construction itself (pad + 9 shifted slices) stays in
-XLA on purpose: it is a pure data-movement op whose transpose is exactly
-col2im, so leaving it outside the kernel gives the dx gradient for free
-through XLA's autodiff while the custom VJP below covers only the
-matmul + bias + ReLU core:
+The im2col patch construction itself (pad + 9 shifted slices,
+``models.cnn.im2col3x3``) stays in XLA on purpose: it is a pure
+data-movement op whose transpose is col2im (nine shifted adds, its own
+custom VJP), so dx comes from there while the custom VJP below covers
+only the matmul + bias + ReLU core:
 
   forward   y  = relu(cols @ W + b)
   backward  dz = dy * (y > 0)
@@ -43,7 +43,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .dispatch import default_interpret
+from repro.models.cnn import im2col3x3
+
+from .dispatch import default_interpret, out_struct
 
 #: Rows of the im2col matrix per program instance.  At the paper's DEFAULT
 #: geometry (K = 9·32 = 288, Cout = 64) one block is 256·288·4 ≈ 0.3 MB —
@@ -86,6 +88,7 @@ def _fwd_call(interpret: bool, cols: jnp.ndarray, wmat: jnp.ndarray,
     n = wmat.shape[1]
     pad = (-m) % TILE_M
     mp = m + pad
+    ops = (_pad_m(cols, pad), wmat, bias[None, :])
     y = pl.pallas_call(
         _fwd_kernel,
         grid=(mp // TILE_M,),
@@ -95,9 +98,9 @@ def _fwd_call(interpret: bool, cols: jnp.ndarray, wmat: jnp.ndarray,
             pl.BlockSpec((1, n), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE_M, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((mp, n), cols.dtype),
+        out_shape=out_struct((mp, n), cols.dtype, *ops),
         interpret=interpret,
-    )(_pad_m(cols, pad), wmat, bias[None, :])
+    )(*ops)
     return y[:m]
 
 
@@ -109,6 +112,7 @@ def _bwd_call(interpret: bool, cols: jnp.ndarray, wmat: jnp.ndarray,
     pad = (-m) % TILE_M
     mp = m + pad
     nt = mp // TILE_M
+    ops = (_pad_m(cols, pad), wmat, _pad_m(y, pad), _pad_m(dy, pad))
     dcols, dw_part, db_part = pl.pallas_call(
         _bwd_kernel,
         grid=(nt,),
@@ -124,12 +128,12 @@ def _bwd_call(interpret: bool, cols: jnp.ndarray, wmat: jnp.ndarray,
             pl.BlockSpec((1, 1, n), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp, k), cols.dtype),
-            jax.ShapeDtypeStruct((nt, k, n), jnp.float32),
-            jax.ShapeDtypeStruct((nt, 1, n), jnp.float32),
+            out_struct((mp, k), cols.dtype, *ops),
+            out_struct((nt, k, n), jnp.float32, *ops),
+            out_struct((nt, 1, n), jnp.float32, *ops),
         ],
         interpret=interpret,
-    )(_pad_m(cols, pad), wmat, _pad_m(y, pad), _pad_m(dy, pad))
+    )(*ops)
     dw = jnp.sum(dw_part, axis=0).astype(wmat.dtype)
     db = jnp.sum(db_part, axis=0)[0].astype(wmat.dtype)
     return dcols[:m], dw, db
@@ -167,14 +171,8 @@ def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     """
     if interpret is None:
         interpret = default_interpret()
-    h, wd = x.shape[-3], x.shape[-2]
     cin, cout = w.shape[2], w.shape[3]
-    pad = [(0, 0)] * (x.ndim - 3) + [(1, 1), (1, 1), (0, 0)]
-    xp = jnp.pad(x, pad)
-    # (i, j, c)-ordered patch channels match w.reshape(9*Cin, Cout) —
-    # identical layout to models.cnn._conv3x3_same_im2col.
-    cols = jnp.concatenate([xp[..., i:i + h, j:j + wd, :]
-                            for i in range(3) for j in range(3)], axis=-1)
+    cols = im2col3x3(x)
     y = _matmul_bias_relu(bool(interpret), cols.reshape(-1, 9 * cin),
                           w.reshape(9 * cin, cout), b)
     return y.reshape(x.shape[:-1] + (cout,))

@@ -2,7 +2,7 @@
 
 Every compute hot-spot with a Pallas kernel has THREE executable forms:
 
-  * ``pallas``    — the compiled ``pallas_call`` (TPU/GPU; fails to lower
+  * ``pallas``    — the compiled ``pallas_call`` (TPU; fails to lower
                     on CPU, which has no Mosaic backend),
   * ``interpret`` — the same kernel through the Pallas interpreter
                     (jax-level emulation: traceable, jittable, correct
@@ -15,7 +15,7 @@ This module is the single place that picks between them.  The knob is a
 ``kernel_mode`` string threaded ``BHFLSimulator``/``run_sweep`` →
 ``run_engine`` (like ``history_dtype``):
 
-  * ``"auto"``      — ``pallas`` on TPU/GPU, ``xla`` on CPU.  The default
+  * ``"auto"``      — ``pallas`` on TPU, ``xla`` on CPU.  The default
                       everywhere: accelerators get the one-HBM-pass fused
                       kernels, CPU keeps the XLA path with zero overhead
                       (never the interpreter loop).
@@ -24,7 +24,7 @@ This module is the single place that picks between them.  The knob is a
 
 ``default_interpret()`` is the companion policy for DIRECT kernel calls
 (``ops.flash_attention``, ``hieavg_agg`` benchmarks): when the caller
-passes ``interpret=None`` the kernel compiles on TPU/GPU and interprets on
+passes ``interpret=None`` the kernel compiles on TPU and interprets on
 CPU — previously ``interpret=True`` was hard-coded "until the launch layer
 flips it off", which nothing ever did, so real hardware silently ran the
 interpreter.
@@ -72,15 +72,16 @@ def fused_phase_coverage(mode: str = "auto") -> dict:
 #: The accepted ``kernel_mode`` values, in resolution order.
 KERNEL_MODES = ("auto", "pallas", "interpret", "xla")
 
-#: Backends with a real Pallas lowering (Mosaic / Triton).
-_COMPILED_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
+#: The backend whose Pallas kernels compile (Mosaic), and the one
+#: accelerator this repository runs on.
+_COMPILED_BACKEND = "tpu"
 
 
 def resolve_kernel_mode(mode: str = "auto") -> str:
     """Resolve a ``kernel_mode`` knob to a concrete path.
 
     ``"auto"`` → ``"pallas"`` when the default jax backend can compile
-    Pallas kernels (TPU/GPU), else ``"xla"`` — never ``"interpret"``: the
+    Pallas kernels (TPU), else ``"xla"`` — never ``"interpret"``: the
     interpreter is a validation tool, not a production path.  Explicit
     modes pass through; unknown strings raise naming the valid set.
     Callers resolve once (host-side) so jit caches key on the concrete
@@ -91,18 +92,32 @@ def resolve_kernel_mode(mode: str = "auto") -> str:
             f"unknown kernel_mode {mode!r}; expected one of {KERNEL_MODES}")
     if mode != "auto":
         return mode
-    return "pallas" if jax.default_backend() in _COMPILED_BACKENDS else "xla"
+    return "pallas" if jax.default_backend() == _COMPILED_BACKEND else "xla"
 
 
 def default_interpret() -> bool:
     """Interpret flag for direct kernel calls when the caller didn't pick:
-    compile on TPU/GPU, interpret on CPU (where Pallas cannot lower)."""
-    return jax.default_backend() not in _COMPILED_BACKENDS
+    compile on TPU, interpret on CPU (where Pallas cannot lower)."""
+    return jax.default_backend() != _COMPILED_BACKEND
 
 
 def _interpret(mode: str) -> bool:
     """The ``pallas_call`` interpret flag for a resolved fused mode."""
     return mode == "interpret"
+
+
+def varying_axes(*trees) -> frozenset:
+    """The manual mesh axes that any leaf of ``trees`` varies over under
+    ``jax.shard_map`` (empty outside it)."""
+    return frozenset().union(*(jax.typeof(x).vma
+                               for x in jax.tree.leaves(trees)))
+
+
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A ``pallas_call`` output type that varies over the mesh axes its
+    operands vary over: ``jax.shard_map``'s type checker needs the kernel's
+    outputs to say so, and the sweep fabric runs the kernels inside it."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=varying_axes(*operands))
 
 
 # --------------------------------------------------------- engine dispatch

@@ -82,7 +82,7 @@ def flash_attention_1h(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     Sq/Skv are padded to the block sizes; D to 128 lanes.  Semantics =
     ``repro.kernels.ref.flash_attention_ref``.  ``interpret=None``
-    auto-detects the backend (compiled on TPU/GPU, interpreter on CPU).
+    auto-detects the backend (compiled on TPU, interpreter on CPU).
     """
     if interpret is None:
         interpret = default_interpret()

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .dispatch import default_interpret
+from .dispatch import default_interpret, out_struct
 
 TILE = 2048
 
@@ -66,7 +66,7 @@ def hieavg_agg(w: jnp.ndarray, prev: jnp.ndarray, dmean: jnp.ndarray,
     Returns (agg [L], new_prev [n, L], new_dmean [n, L]).  Semantics =
     ``repro.kernels.ref.hieavg_agg_ref``.  ``interpret=None`` auto-detects
     the backend (``dispatch.default_interpret``): compiled ``pallas_call``
-    on TPU/GPU, interpreter on CPU.  History leaves (``prev``/``dmean``)
+    on TPU, interpreter on CPU.  History leaves (``prev``/``dmean``)
     may carry a narrower storage dtype than ``w`` (the engine's
     ``history_dtype`` knob) — math is f32, each output casts back to its
     own operand's dtype.
@@ -86,6 +86,7 @@ def hieavg_agg(w: jnp.ndarray, prev: jnp.ndarray, dmean: jnp.ndarray,
                      n_obs.astype(jnp.float32)])           # [4, n]
 
     grid = (lp // TILE,)
+    ops = (w, prev, dmean, vec)
     agg, nprev, ndmean = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -101,10 +102,10 @@ def hieavg_agg(w: jnp.ndarray, prev: jnp.ndarray, dmean: jnp.ndarray,
             pl.BlockSpec((n, TILE), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, lp), w.dtype),
-            jax.ShapeDtypeStruct((n, lp), prev.dtype),
-            jax.ShapeDtypeStruct((n, lp), dmean.dtype),
+            out_struct((1, lp), w.dtype, *ops),
+            out_struct((n, lp), prev.dtype, *ops),
+            out_struct((n, lp), dmean.dtype, *ops),
         ],
         interpret=interpret,
-    )(w, prev, dmean, vec)
+    )(*ops)
     return agg[0, :l], nprev[:, :l], ndmean[:, :l]

@@ -32,7 +32,7 @@ kernel: batch, kv-head and group dims are vmapped (Pallas prepends them as
 grid dimensions).
 
 Every wrapper takes ``interpret=None`` = backend auto-detection
-(``dispatch.default_interpret``): compiled ``pallas_call`` on TPU/GPU,
+(``dispatch.default_interpret``): compiled ``pallas_call`` on TPU,
 interpreter on CPU.  The engine does not call these directly — it goes
 through ``kernels.dispatch`` so ``kernel_mode="xla"``/``"auto"`` can route
 to the pure-XLA reference path instead.

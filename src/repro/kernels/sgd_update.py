@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .dispatch import default_interpret
+from .dispatch import default_interpret, out_struct
 
 TILE = 2048
 
@@ -43,7 +43,7 @@ def sgd_update(w: jnp.ndarray, g: jnp.ndarray, scale: jnp.ndarray,
 
     ``scale`` is a (possibly traced) scalar — lr × step-validity, so 0
     makes the update an exact identity.  ``interpret=None`` auto-detects
-    the backend (compiled on TPU/GPU, interpreter on CPU).  Semantics =
+    the backend (compiled on TPU, interpreter on CPU).  Semantics =
     ``repro.kernels.ref.sgd_update_ref``.
     """
     if interpret is None:
@@ -65,7 +65,7 @@ def sgd_update(w: jnp.ndarray, g: jnp.ndarray, scale: jnp.ndarray,
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((n, TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, lp), w.dtype),
+        out_shape=out_struct((n, lp), w.dtype, w, g, s),
         interpret=interpret,
     )(w, g, s)
     return out[:, :l]

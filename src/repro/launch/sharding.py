@@ -34,7 +34,7 @@ from repro.models.spec import PyTree
 # ``resolve_kernel_mode`` is re-exported here on purpose: the launch layer
 # resolves WHERE a program runs (mesh placement, the autoscaling specs
 # below) and HOW its hot loops execute (kernel-plane backend — compiled
-# Pallas on TPU/GPU, XLA reference on CPU) side by side, from the same
+# Pallas on TPU, XLA reference on CPU) side by side, from the same
 # runtime facts.  The policy itself lives in ``repro.kernels.dispatch`` so
 # the kernel plane stays self-contained.
 

@@ -15,7 +15,7 @@ mid-run checkpoints; otherwise identical math — the fused path consumes
 the batch/chain RNG streams in the same order).
 
 ``kernel_mode`` is the kernel-plane knob (``repro.kernels.dispatch``):
-``"auto"`` resolves to the Pallas flash-attention kernel on TPU/GPU and
+``"auto"`` resolves to the Pallas flash-attention kernel on TPU and
 the XLA einsum path on CPU.  This driver refuses ``"interpret"`` — the
 Pallas interpreter is a test/validation tool, not a production path.
 
@@ -56,7 +56,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20, k_edge: int = 2,
         raise ValueError(
             "train.run(kernel_mode='interpret'): the Pallas interpreter is "
             "a test/validation path, not a training backend — use 'auto', "
-            "'pallas' (TPU/GPU), or 'xla'")
+            "'pallas' (TPU), or 'xla'")
     cfg = get_smoke(arch) if smoke else get_config(arch)
     mesh = make_debug_mesh() if smoke else make_production_mesh()
     e, c = 1 if smoke else 2, n_clients

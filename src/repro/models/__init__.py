@@ -7,7 +7,8 @@ from .spec import (ParamSpec, init_from_specs, abstract_from_specs,
 from .transformer import (param_specs, cache_specs, forward_train, loss_fn,
                           prefill, decode_step, encode)
 from .cnn import (cnn_specs, cnn_apply, cnn_apply_fast, cnn_loss,
-                  cnn_loss_fast, cnn_accuracy, cnn_accuracy_fast)
+                  cnn_loss_fast, cnn_accuracy, cnn_accuracy_fast,
+                  cnn_correct_fast)
 
 __all__ = [
     "ArchConfig", "EncoderConfig", "InputShape", "MLAConfig", "MoEConfig",
@@ -18,5 +19,5 @@ __all__ = [
     "param_specs", "cache_specs", "forward_train", "loss_fn", "prefill",
     "decode_step", "encode",
     "cnn_specs", "cnn_apply", "cnn_apply_fast", "cnn_loss", "cnn_loss_fast",
-    "cnn_accuracy", "cnn_accuracy_fast",
+    "cnn_accuracy", "cnn_accuracy_fast", "cnn_correct_fast",
 ]

@@ -43,6 +43,43 @@ def _conv3x3_same(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
+_TAPS = tuple((i, j) for i in range(3) for j in range(3))
+
+
+@jax.custom_vjp
+def im2col3x3(x: jnp.ndarray) -> jnp.ndarray:
+    """SAME-padded 3x3 patches: [..., H, W, Cin] -> [..., H, W, 9·Cin].
+
+    Patch channels are (i, j, c)-ordered, matching ``w.reshape(9·Cin,
+    Cout)``.  The backward is col2im written out as nine shifted adds.
+    XLA's own transpose of the concatenate instead splits the cotangent
+    into a ``[..., Cin, 9]`` buffer whose 9-wide minor dim a TPU pads to
+    128 lanes: at the paper's widths that one buffer is over 10 GB for a
+    3-point sweep.
+    """
+    h, wd = x.shape[-3], x.shape[-2]
+    pad = [(0, 0)] * (x.ndim - 3) + [(1, 1), (1, 1), (0, 0)]
+    xp = jnp.pad(x, pad)
+    return jnp.concatenate([xp[..., i:i + h, j:j + wd, :]
+                            for i, j in _TAPS], axis=-1)
+
+
+def _im2col_fwd(x):
+    return im2col3x3(x), None
+
+
+def _im2col_bwd(_, dcols):
+    h, wd, c = dcols.shape[-3], dcols.shape[-2], dcols.shape[-1] // 9
+    dxp = jnp.zeros(dcols.shape[:-3] + (h + 2, wd + 2, c), dcols.dtype)
+    for k, (i, j) in enumerate(_TAPS):
+        dxp = dxp.at[..., i:i + h, j:j + wd, :].add(
+            dcols[..., k * c:(k + 1) * c])
+    return (dxp[..., 1:h + 1, 1:wd + 1, :],)
+
+
+im2col3x3.defvjp(_im2col_fwd, _im2col_bwd)
+
+
 def _conv3x3_same_im2col(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """3x3 SAME conv as ONE batched matmul (im2col).
 
@@ -53,13 +90,8 @@ def _conv3x3_same_im2col(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     batched engine trains with this form, the legacy reference loop keeps
     the shifted sum.  x: [..., H, W, Cin]; w: [3, 3, Cin, Cout].
     """
-    h, wd = x.shape[-3], x.shape[-2]
-    pad = [(0, 0)] * (x.ndim - 3) + [(1, 1), (1, 1), (0, 0)]
-    xp = jnp.pad(x, pad)
-    # (i, j, c)-ordered patch channels match w.reshape(9*Cin, Cout)
-    cols = jnp.concatenate([xp[..., i:i + h, j:j + wd, :]
-                            for i in range(3) for j in range(3)], axis=-1)
-    return jnp.einsum("...k,ko->...o", cols, w.reshape(-1, w.shape[-1]))
+    return jnp.einsum("...k,ko->...o", im2col3x3(x),
+                      w.reshape(-1, w.shape[-1]))
 
 
 def _pool_flatten(x: jnp.ndarray) -> jnp.ndarray:
@@ -141,21 +173,29 @@ def cnn_accuracy(params: dict, images: jnp.ndarray, labels: jnp.ndarray
     return _accuracy(cnn_apply, params, images, labels)
 
 
-def cnn_accuracy_fast(params: dict, images: jnp.ndarray, labels: jnp.ndarray,
-                      kernel_mode: str = "xla") -> jnp.ndarray:
-    """``cnn_accuracy`` on the im2col forward (the engine's eval path).
+def cnn_correct_fast(params: dict, images: jnp.ndarray, labels: jnp.ndarray,
+                     kernel_mode: str = "xla") -> jnp.ndarray:
+    """Int32 count of correct predictions on the im2col forward (the
+    engine's eval path); a label of -1 never counts.
 
     Under a fused ``kernel_mode`` the whole eval runs kernel-routed: conv
     blocks through the fused conv kernel, then the classifier head as one
     logits → argmax → correct-count pass (``kernels.dispatch.eval_head``)
-    — the logits buffer never materializes.  Count / #rows equals the
-    mean-of-hits the XLA path computes (both exact in f32).
+    — the logits buffer never materializes.
     """
     from repro.kernels import dispatch as _kd
     mode = _kd.resolve_kernel_mode(kernel_mode)
     if mode == "xla":
-        return _accuracy(cnn_apply_fast, params, images, labels)
+        pred = jnp.argmax(cnn_apply_fast(params, images), -1)
+        return jnp.sum((pred == labels).astype(jnp.int32))
     feats = _features_fused(params, images, mode)
-    count = _kd.eval_head(feats, params["dense"], params["b3"], labels,
-                          mode=mode)
+    return _kd.eval_head(feats, params["dense"], params["b3"], labels,
+                         mode=mode)
+
+
+def cnn_accuracy_fast(params: dict, images: jnp.ndarray, labels: jnp.ndarray,
+                      kernel_mode: str = "xla") -> jnp.ndarray:
+    """``cnn_accuracy`` through ``cnn_correct_fast``: count / #rows, the
+    same f32 value as a mean of hits (both exact)."""
+    count = cnn_correct_fast(params, images, labels, kernel_mode)
     return count.astype(jnp.float32) / labels.shape[0]

@@ -222,9 +222,9 @@ def test_conv3x3_matches_ref_on_tile_tails(b, hw, cin, cout):
 
 @pytest.mark.kernel_oracle
 def test_conv3x3_grads_match_ref():
-    """The custom VJP: dx (via the XLA col2im autodiff of the im2col
-    construction), dw and db (the Pallas backward matmuls) against the
-    pure-jnp reference's autodiff."""
+    """The custom VJPs: dx (the col2im of ``models.cnn.im2col3x3``), dw
+    and db (the Pallas backward matmuls) against the pure-jnp reference's
+    autodiff."""
     ks = jax.random.split(jax.random.key(1), 4)
     x = jax.random.normal(ks[0], (2, 9, 9, 3))
     w = jax.random.normal(ks[1], (3, 3, 3, 5)) * 0.3
@@ -242,6 +242,49 @@ def test_conv3x3_grads_match_ref():
     np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), atol=1e-4)
     np.testing.assert_allclose(np.asarray(gw), np.asarray(rw), atol=1e-4)
     np.testing.assert_allclose(np.asarray(gb), np.asarray(rb), atol=1e-4)
+
+
+@pytest.mark.kernel_oracle
+@pytest.mark.parametrize("shape", [(2, 9, 9, 3), (2, 3, 8, 8, 1),
+                                   (1, 5, 7, 4)])
+def test_im2col_col2im_vjp_matches_autodiff(shape):
+    """``im2col3x3``'s hand-written col2im equals XLA's transpose of the
+    plain pad + slice + concatenate, on leading batch dims and odd
+    spatial extents."""
+    from repro.models.cnn import im2col3x3
+
+    def plain(x):
+        h, wd = x.shape[-3], x.shape[-2]
+        xp = jnp.pad(x, [(0, 0)] * (x.ndim - 3) + [(1, 1), (1, 1), (0, 0)])
+        return jnp.concatenate([xp[..., i:i + h, j:j + wd, :]
+                                for i in range(3) for j in range(3)], -1)
+
+    ks = jax.random.split(jax.random.key(7), 2)
+    x = jax.random.normal(ks[0], shape)
+    cols, vjp = jax.vjp(im2col3x3, x)
+    ref_cols, ref_vjp = jax.vjp(plain, x)
+    np.testing.assert_array_equal(np.asarray(cols), np.asarray(ref_cols))
+    dcols = jax.random.normal(ks[1], cols.shape)
+    np.testing.assert_allclose(np.asarray(vjp(dcols)[0]),
+                               np.asarray(ref_vjp(dcols)[0]), atol=1e-5)
+
+
+@pytest.mark.kernel_oracle
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+@pytest.mark.parametrize("chunk", [16, 100, 250])
+def test_eval_accuracy_in_chunks_equals_whole_set(mode, chunk, monkeypatch):
+    """The engine's chunked test-set eval (tail chunk padded with label
+    -1) gives bitwise the accuracy of one whole-set pass."""
+    from repro.fl import engine
+    from repro.models import cnn_accuracy_fast, cnn_specs, init_from_specs
+
+    monkeypatch.setattr(engine, "EVAL_CHUNK", chunk)
+    ks = jax.random.split(jax.random.key(8), 3)
+    params = init_from_specs(cnn_specs(image_hw=8, c1=4, c2=8), ks[0])
+    x = jax.random.normal(ks[1], (100, 8, 8, 1))
+    y = jax.random.randint(ks[2], (100,), 0, 10)
+    got = engine.eval_accuracy(params, x, y, mode)
+    assert float(got) == float(cnn_accuracy_fast(params, x, y, mode))
 
 
 @pytest.mark.kernel_oracle

@@ -205,6 +205,27 @@ def test_forced_shard_raises_clearly_on_one_device():
                   placement="shard", **KW)
 
 
+@pytest.mark.parametrize("point_batch", [1, 2])
+def test_point_batch_walks_points_with_the_same_results(point_batch):
+    """``point_batch`` walks a bucket's points n at a time inside its one
+    compiled call (bounding device memory); per point the numbers match
+    the all-at-once vmap to float rounding, including a tail batch."""
+    ovs = [{"aggregation": "hieavg"}, {"aggregation": "delayed_grad"},
+           {"aggregation": "fedavg"}]
+    ref = run_sweep(TINY, overrides=ovs, **KW)
+    got = run_sweep(TINY, overrides=ovs, point_batch=point_batch, **KW)
+    np.testing.assert_allclose(got.accuracy, ref.accuracy, atol=1e-6)
+    np.testing.assert_allclose(got.loss, ref.loss, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got.grad_norm, ref.grad_norm, rtol=1e-4,
+                               atol=1e-6)
+    np.testing.assert_array_equal(got.sim_clock, ref.sim_clock)
+
+
+def test_point_batch_must_be_positive():
+    with pytest.raises(ValueError, match="point_batch"):
+        run_sweep(TINY, overrides=[{}], point_batch=0, **KW)
+
+
 # ---------------------------------------------------------- history dtype
 def test_history_dtype_f8_runs_and_stays_close():
     """EXPERIMENTS.md X1: f8 history storage is a memory/accuracy knob,
