@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import baselines, hieavg
 from repro.core import latency as lat
 from repro.core import rng as rng_streams
@@ -114,8 +115,12 @@ def train_epoch_body(params: PyTree, images: jnp.ndarray,
         else:
             im, lb, ok = xs
             scale = lr * ok
-        loss, g = jax.vmap(jax.value_and_grad(loss_fn))(ps, im, lb)
-        ps = kernel_dispatch.sgd_update(ps, g, scale, mode=kernel_mode)
+        # the scope again inside the scanned body: XLA names what it
+        # expands from a scatter (the im2col backward's col2im) after the
+        # reducer, whose op_name is relative to this body
+        with jax.named_scope(telemetry.TRAIN):
+            loss, g = jax.vmap(jax.value_and_grad(loss_fn))(ps, im, lb)
+            ps = kernel_dispatch.sgd_update(ps, g, scale, mode=kernel_mode)
         return ps, loss
 
     images = jnp.swapaxes(images, 0, 1)                 # [steps, D, ...]
@@ -340,6 +345,7 @@ def replay_chain(sim) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     return cons, energy, edge_avail
 
 
+@telemetry.span("inputs.build")
 def build_inputs(sim, *, t_max: Optional[int] = None,
                  k_max: Optional[int] = None, n_max: Optional[int] = None,
                  j_max: Optional[int] = None,
@@ -379,7 +385,8 @@ def build_inputs(sim, *, t_max: Optional[int] = None,
             or (j_max is not None and j_max < max(sim.j_per_edge))):
         raise ValueError("pad targets must be >= the deployment's extents")
 
-    cons_draws, energy_draws, edge_avail = replay_chain(sim)
+    with telemetry.span("inputs.replay_chain"):
+        cons_draws, energy_draws, edge_avail = replay_chain(sim)
 
     dense_dev, valid = strag.stack_ragged(sim.dev_masks, j_max=j_max,
                                           n_max=Nm)
@@ -407,77 +414,78 @@ def build_inputs(sim, *, t_max: Optional[int] = None,
     edge_masks[:T, :N] = np.asarray(sim.edge_masks[:T], dtype=bool) \
         & edge_avail
 
+    R = T * K
+    # device d's slot (edge, index) in the dense [N, J] layout
+    slots = [(e, j) for e in range(N) for j in range(sim.j_per_edge[e])]
     # batch indices in legacy order: per edge-round, per device.  The
     # fresh generator rides the deployment's "batches" SeedSequence stream
     # (core.rng) — the same stream run_legacy opens per run, so a legacy
     # and an engine run of one instance see identical batches.
-    rng = rng_streams.stream_rng(sim.seed, "batches")
-    R = T * K
-    if getattr(sim, "pop", None) is not None:
-        # population mode: one vectorized draw for all (round, slot)
-        # pairs — the occupant's classes select the sample pools, the
-        # draws are slot-keyed.  O(R x cohort), never O(population).
-        ids_r = np.repeat(sim.cohort_ids, K, axis=0).reshape(R, sim.D)
-        cls_rd = sim.pop.classes[ids_r.reshape(-1)]      # [R*D, M]
-        flat_idx = partition.sample_class_batches(
-            sim._pool, sim._pool_off, sim._pool_cnt, cls_rd, steps, bs,
-            rng).reshape(R, sim.D, steps, bs)
-        flat_has = np.ones((sim.D,), np.float32)
-    else:
-        flat_idx = np.zeros((R, sim.D, steps, bs), np.int32)
-        flat_has = np.zeros((sim.D,), np.float32)
-        for r in range(R):
-            for d, idx in enumerate(sim.device_idx):
-                if len(idx) == 0:
-                    continue
-                flat_idx[r, d] = rng.choice(idx, size=(steps, bs),
-                                            replace=True)
-                flat_has[d] = 1.0
+    with telemetry.span("inputs.batches"):
+        rng = rng_streams.stream_rng(sim.seed, "batches")
+        if getattr(sim, "pop", None) is not None:
+            # population mode: one vectorized draw for all (round, slot)
+            # pairs — the occupant's classes select the sample pools, the
+            # draws are slot-keyed.  O(R x cohort), never O(population).
+            ids_r = np.repeat(sim.cohort_ids, K, axis=0).reshape(R, sim.D)
+            cls_rd = sim.pop.classes[ids_r.reshape(-1)]      # [R*D, M]
+            flat_idx = partition.sample_class_batches(
+                sim._pool, sim._pool_off, sim._pool_cnt, cls_rd, steps, bs,
+                rng).reshape(R, sim.D, steps, bs)
+            flat_has = np.ones((sim.D,), np.float32)
+        else:
+            flat_idx = np.zeros((R, sim.D, steps, bs), np.int32)
+            flat_has = np.zeros((sim.D,), np.float32)
+            for r in range(R):
+                for d, idx in enumerate(sim.device_idx):
+                    if len(idx) == 0:
+                        continue
+                    flat_idx[r, d] = rng.choice(idx, size=(steps, bs),
+                                                replace=True)
+                    flat_has[d] = 1.0
+        batch_idx = np.zeros((Tm, Km, Nm, J, Sm, bs), np.int32)
+        has_data = np.zeros((Nm, J), np.float32)
+        rect = flat_idx.reshape(T, K, sim.D, steps, bs)
+        for d, (e, j) in enumerate(slots):
+            batch_idx[:T, :K, e, j, :steps] = rect[:, :, d]
+            has_data[e, j] = flat_has[d]
     # per-device round-time draws (latency fabric).  A separate RNG stream
     # from the batch sampler above: adding latency accounting must not
     # perturb batch draws (legacy parity).  Draws cover only the REAL
     # (T, K, D) extents so a point padded to larger grid maxima sees
     # byte-identical times (padding stays a numeric no-op).  Population
     # mode scales each slot's draw by the round occupant's speed profile.
-    lp = sim.lat
-    lrng = rng_streams.stream_rng(sim.seed, "latency")
-    jm = lrng.uniform(1.0 - lp.lm_jitter, 1.0 + lp.lm_jitter, (R, sim.D))
-    jp = lrng.uniform(1.0 - lp.lp_jitter, 1.0 + lp.lp_jitter, (R, sim.D))
-    draw = 2.0 * lp.lm_device * jm + lp.lp_device * jp
-    spd = sim.cohort_time_scale() if getattr(sim, "pop", None) is not None \
-        else None
-    if spd is not None:
-        draw = draw * spd
-    elif lp.rate_mult is not None:
-        # heterogeneous fleet: device d's clock rate scales every one of
-        # its round draws (before straggler slowdown / deadline capping,
-        # exactly like a population occupant's time_scale would)
-        rm = np.asarray(lp.rate_mult, np.float64).reshape(-1)
-        if rm.shape != (sim.D,):
-            raise ValueError(
-                f"LatencyParams.rate_mult must have one entry per device "
-                f"({sim.D}), got shape {rm.shape}")
-        draw = draw * rm[None, :]
-    draw = draw.reshape(T, K, sim.D)
-    deadline = lat.device_deadline(lp)
-    sub = dense_dev[:R].reshape(T, K, Nm, J)    # real submission masks
-
-    batch_idx = np.zeros((Tm, Km, Nm, J, Sm, bs), np.int32)
-    has_data = np.zeros((Nm, J), np.float32)
-    dev_time = np.zeros((Tm, Km, Nm, J), np.float32)
-    rect = flat_idx.reshape(T, K, sim.D, steps, bs)
-    d = 0
-    for e in range(N):
-        for j in range(sim.j_per_edge[e]):
-            batch_idx[:T, :K, e, j, :steps] = rect[:, :, d]
-            has_data[e, j] = flat_has[d]
+    with telemetry.span("inputs.latency"):
+        lp = sim.lat
+        lrng = rng_streams.stream_rng(sim.seed, "latency")
+        jm = lrng.uniform(1.0 - lp.lm_jitter, 1.0 + lp.lm_jitter, (R, sim.D))
+        jp = lrng.uniform(1.0 - lp.lp_jitter, 1.0 + lp.lp_jitter, (R, sim.D))
+        draw = 2.0 * lp.lm_device * jm + lp.lp_device * jp
+        spd = sim.cohort_time_scale() \
+            if getattr(sim, "pop", None) is not None else None
+        if spd is not None:
+            draw = draw * spd
+        elif lp.rate_mult is not None:
+            # heterogeneous fleet: device d's clock rate scales every one of
+            # its round draws (before straggler slowdown / deadline capping,
+            # exactly like a population occupant's time_scale would)
+            rm = np.asarray(lp.rate_mult, np.float64).reshape(-1)
+            if rm.shape != (sim.D,):
+                raise ValueError(
+                    f"LatencyParams.rate_mult must have one entry per device "
+                    f"({sim.D}), got shape {rm.shape}")
+            draw = draw * rm[None, :]
+        draw = draw.reshape(T, K, sim.D)
+        deadline = lat.device_deadline(lp)
+        sub = dense_dev[:R].reshape(T, K, Nm, J)    # real submission masks
+        dev_time = np.zeros((Tm, Km, Nm, J), np.float32)
+        for d, (e, j) in enumerate(slots):
             # a straggler's submission is delayed (slowdown x draw); the
             # edge proceeds at the deadline without it — deadline-based
             # aggregation, so its round time is capped there
             dly = np.where(sub[:, :, e, j], draw[:, :, d],
                            draw[:, :, d] * lp.straggler_slowdown)
             dev_time[:T, :K, e, j] = np.minimum(dly, deadline)
-            d += 1
     cons_time = np.zeros((Tm,), np.float32)
     cons_time[:T] = cons_draws * float(s.consensus_mult)
     # energy is a protocol cost, not a latency knob: consensus_mult never
@@ -498,40 +506,42 @@ def build_inputs(sim, *, t_max: Optional[int] = None,
         chg = sim.cohort_change()
         cohort_change[:T, :N, :chg.shape[2]] = chg
 
-    if share_data_from is not None:
-        src = share_data_from
-        train_x, train_y = src.train_x, src.train_y
-        test_x, test_y, init_w = src.test_x, src.test_y, src.init_w
-    else:
-        # [None]: the seed-major [S=1] axis (a reshape of the device
-        # buffer, not a copy)
-        train_x = jnp.asarray(sim.train_x)[None]
-        train_y = jnp.asarray(sim.train_y)[None]
-        test_x = jnp.asarray(sim.test_x)[None]
-        test_y = jnp.asarray(sim.test_y)[None]
-        init_w = jax.tree.map(
-            lambda x: x[None],
-            init_from_specs(sim.specs, jax.random.key(sim.seed)))
+    with telemetry.span("inputs.to_device"):
+        if share_data_from is not None:
+            src = share_data_from
+            train_x, train_y = src.train_x, src.train_y
+            test_x, test_y, init_w = src.test_x, src.test_y, src.init_w
+        else:
+            # [None]: the seed-major [S=1] axis (a reshape of the device
+            # buffer, not a copy)
+            train_x = jnp.asarray(sim.train_x)[None]
+            train_y = jnp.asarray(sim.train_y)[None]
+            test_x = jnp.asarray(sim.test_x)[None]
+            test_y = jnp.asarray(sim.test_y)[None]
+            init_w = jax.tree.map(
+                lambda x: x[None],
+                init_from_specs(sim.specs, jax.random.key(sim.seed)))
 
-    return EngineInputs(
-        train_x=train_x, train_y=train_y,
-        test_x=test_x, test_y=test_y, init_w=init_w,
-        seed_idx=jnp.int32(0),
-        batch_idx=jnp.asarray(batch_idx),
-        has_data=jnp.asarray(has_data), valid=jnp.asarray(valid),
-        dev_masks=jnp.asarray(dev_masks), edge_masks=jnp.asarray(edge_masks),
-        lr=jnp.asarray(lr), j_arr=jnp.asarray(j_arr),
-        gamma0=jnp.float32(s.gamma0), lam=jnp.float32(s.lam),
-        t_cold_boot=jnp.int32(s.t_cold_boot),
-        t_valid=jnp.int32(T), k_valid=jnp.int32(K),
-        n_valid=jnp.int32(N), s_valid=jnp.int32(steps),
-        dev_time=jnp.asarray(dev_time), cons_time=jnp.asarray(cons_time),
-        cons_energy=jnp.asarray(cons_energy),
-        edge_hop=jnp.float32(2.0 * lp.lm_edge),
-        cohort_change=jnp.asarray(cohort_change),
-        agg_sel=jnp.int32(AGG_SEL.get(sim.aggregator, 0)),
-        stale_beta=jnp.float32(s.staleness_discount),
-        delay_delta=jnp.float32(s.delay_delta))
+        return EngineInputs(
+            train_x=train_x, train_y=train_y,
+            test_x=test_x, test_y=test_y, init_w=init_w,
+            seed_idx=jnp.int32(0),
+            batch_idx=jnp.asarray(batch_idx),
+            has_data=jnp.asarray(has_data), valid=jnp.asarray(valid),
+            dev_masks=jnp.asarray(dev_masks),
+            edge_masks=jnp.asarray(edge_masks),
+            lr=jnp.asarray(lr), j_arr=jnp.asarray(j_arr),
+            gamma0=jnp.float32(s.gamma0), lam=jnp.float32(s.lam),
+            t_cold_boot=jnp.int32(s.t_cold_boot),
+            t_valid=jnp.int32(T), k_valid=jnp.int32(K),
+            n_valid=jnp.int32(N), s_valid=jnp.int32(steps),
+            dev_time=jnp.asarray(dev_time), cons_time=jnp.asarray(cons_time),
+            cons_energy=jnp.asarray(cons_energy),
+            edge_hop=jnp.float32(2.0 * lp.lm_edge),
+            cohort_change=jnp.asarray(cohort_change),
+            agg_sel=jnp.int32(AGG_SEL.get(sim.aggregator, 0)),
+            stale_beta=jnp.float32(s.staleness_discount),
+            delay_delta=jnp.float32(s.delay_delta))
 
 
 # ------------------------------------------------------------- jitted run
@@ -731,24 +741,9 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
          chg_t) = xs
 
         # ---- K edge rounds: local epoch + per-edge aggregation + sync
-        def edge_round(c, xs_k):
-            prev_c = c
-            device_w, ehist, elast, eage = c
-            # [N,J,steps,B], [N,J], scalar lr, round counter r, k index,
-            # per-device time draws [N,J]
-            bidx, dmask, lr, r, k, dtime = xs_k
-
-            x = inp.train_x[inp.seed_idx, bidx] \
-                * hd[:, :, None, None, None, None, None]
-            y = jnp.where(hd[:, :, None, None] > 0,
-                          inp.train_y[inp.seed_idx, bidx], 0)
-            pflat, loss = train_epoch_body(
-                flat(device_w), x.reshape((D, steps, bs) + x.shape[4:]),
-                y.reshape(D, steps, bs), lr, step_ok=step_ok,
-                kernel_mode=kernel_mode)
-            ws = unflat(pflat)
-            dev_loss = loss.reshape(N, J)
-
+        def edge_aggregate(ws, dmask, r, k, ehist, elast, eage):
+            """Every edge's aggregation of its slots' models ``ws``,
+            broadcast back to the slots: the new edge-round carry."""
             if aggregator in ("hieavg", "switched"):
                 ehist = jax.lax.cond(
                     r == 0,
@@ -808,69 +803,101 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
             else:
                 raise ValueError(f"unknown aggregator {aggregator!r}")
 
-            new_c = (bcast_devices(edge_models), ehist, elast, eage)
+            return (bcast_devices(edge_models), ehist, elast, eage)
+
+        def edge_round(c, xs_k):
+            prev_c = c
+            device_w, ehist, elast, eage = c
+            # [N,J,steps,B], [N,J], scalar lr, round counter r, k index,
+            # per-device time draws [N,J]
+            bidx, dmask, lr, r, k, dtime = xs_k
+
+            with jax.named_scope(telemetry.TRAIN):
+                x = inp.train_x[inp.seed_idx, bidx] \
+                    * hd[:, :, None, None, None, None, None]
+                y = jnp.where(hd[:, :, None, None] > 0,
+                              inp.train_y[inp.seed_idx, bidx], 0)
+                pflat, loss = train_epoch_body(
+                    flat(device_w), x.reshape((D, steps, bs) + x.shape[4:]),
+                    y.reshape(D, steps, bs), lr, step_ok=step_ok,
+                    kernel_mode=kernel_mode)
+            # outside the scope: XLA merges this reshape with the edge
+            # aggregation's, and the merged op_name would name both phases
+            ws = unflat(pflat)
+            dev_loss = loss.reshape(N, J)
+
+            with jax.named_scope(telemetry.EDGE_AGG):
+                edge_c = edge_aggregate(ws, dmask, r, k, ehist, elast, eage)
             # per-edge elapsed: the slowest valid device closes the round
             # (padded slots carry dev_time 0; padded edge rounds count 0)
             el = jnp.max(jnp.where(inp.valid, dtime, 0.0), axis=1)
             el = el * (k < inp.k_valid)
             # padded edge round (k >= k_valid): carry passes through
-            return passthru(k < inp.k_valid, new_c, prev_c), (dev_loss, el)
+            return passthru(k < inp.k_valid, edge_c, prev_c), (dev_loss, el)
 
         ks = jnp.arange(K)
         rs = (t - 1) * K + ks
         (device_w, ehist, elast, eage), (dev_losses, edge_els) = jax.lax.scan(
             edge_round, (device_w, ehist, elast, eage),
             (bidx_t, dmask_t, lr_t, rs, ks, dtime_t))
-        # after the sync every device slot holds its edge model
-        edge_models = jax.tree.map(lambda x: x[:, 0], device_w)
 
         # ---- global aggregation on the (replayed) leader
-        if aggregator in ("hieavg", "switched"):
-            ghist = jax.lax.cond(
-                t == 1,
-                lambda h: _vary_like(
-                    hieavg.init_history(edge_models, history_dtype), h),
-                lambda h: h, ghist)
-            pw = inp.j_arr / jnp.sum(inp.j_arr)
+        def global_aggregate(device_w, ghist, glast, gage):
+            """The leader's aggregation of the edge models, broadcast back
+            to the device slots."""
+            # after the sync every device slot holds its edge model
+            edge_models = jax.tree.map(lambda x: x[:, 0], device_w)
+            if aggregator in ("hieavg", "switched"):
+                ghist = jax.lax.cond(
+                    t == 1,
+                    lambda h: _vary_like(
+                        hieavg.init_history(edge_models, history_dtype), h),
+                    lambda h: h, ghist)
+                pw = inp.j_arr / jnp.sum(inp.j_arr)
 
-            def coldg(w, m, h):
-                return (kernel_dispatch.global_aggregate_cold(
-                    w, inp.j_arr, mode=kernel_mode),
-                        hieavg.update_history(h, w, m))
+                def coldg(w, m, h):
+                    return (kernel_dispatch.global_aggregate_cold(
+                        w, inp.j_arr, mode=kernel_mode),
+                            hieavg.update_history(h, w, m))
 
-            def warmg(w, m, h):
-                return kernel_dispatch.global_aggregate(
-                    w, m, h, pw, inp.gamma0, inp.lam, normalize,
-                    mode=kernel_mode)
+                def warmg(w, m, h):
+                    return kernel_dispatch.global_aggregate(
+                        w, m, h, pw, inp.gamma0, inp.lam, normalize,
+                        mode=kernel_mode)
 
-            gagg_h, ghist = jax.lax.cond(
-                t <= inp.t_cold_boot, coldg, warmg, edge_models, emask, ghist)
-        if aggregator in ("delayed_grad", "switched"):
-            # edges are fixed infrastructure — no churn reset at this layer
-            m_eff = jnp.logical_or(emask, t == 1)
-            gagg_d, glast, gage = kernel_dispatch.delayed_grad(
-                edge_models, m_eff, glast, gage, inp.stale_beta,
-                inp.delay_delta, inp.j_arr, mode=kernel_mode)
+                gagg_h, ghist = jax.lax.cond(
+                    t <= inp.t_cold_boot, coldg, warmg, edge_models, emask,
+                    ghist)
+            if aggregator in ("delayed_grad", "switched"):
+                # edges are fixed infrastructure — no churn reset here
+                m_eff = jnp.logical_or(emask, t == 1)
+                gagg_d, glast, gage = kernel_dispatch.delayed_grad(
+                    edge_models, m_eff, glast, gage, inp.stale_beta,
+                    inp.delay_delta, inp.j_arr, mode=kernel_mode)
 
-        if aggregator == "hieavg":
-            global_w = gagg_h
-        elif aggregator == "delayed_grad":
-            global_w = gagg_d
-        elif aggregator == "t_fedavg":
-            global_w = baselines.t_fedavg(edge_models, emask, inp.j_arr)
-        elif aggregator == "d_fedavg":
-            m_eff = jnp.logical_or(emask, t == 1)
-            global_w, glast = baselines.d_fedavg(
-                edge_models, m_eff, glast, inp.j_arr)
-        elif aggregator == "switched":
-            global_w = sel3(inp.agg_sel, gagg_h, gagg_d,
-                            kernel_dispatch.fedavg(edge_models, inp.j_arr,
-                                                   mode=kernel_mode))
-        else:
-            global_w = kernel_dispatch.fedavg(edge_models, inp.j_arr,
-                                              mode=kernel_mode)
+            if aggregator == "hieavg":
+                global_w = gagg_h
+            elif aggregator == "delayed_grad":
+                global_w = gagg_d
+            elif aggregator == "t_fedavg":
+                global_w = baselines.t_fedavg(edge_models, emask, inp.j_arr)
+            elif aggregator == "d_fedavg":
+                m_eff = jnp.logical_or(emask, t == 1)
+                global_w, glast = baselines.d_fedavg(
+                    edge_models, m_eff, glast, inp.j_arr)
+            elif aggregator == "switched":
+                global_w = sel3(inp.agg_sel, gagg_h, gagg_d,
+                                kernel_dispatch.fedavg(edge_models, inp.j_arr,
+                                                       mode=kernel_mode))
+            else:
+                global_w = kernel_dispatch.fedavg(edge_models, inp.j_arr,
+                                                  mode=kernel_mode)
+            return (bcast_devices(bcast_edges(global_w)), global_w, ghist,
+                    glast, gage)
 
-        device_w = bcast_devices(bcast_edges(global_w))
+        with jax.named_scope(telemetry.GLOBAL_AGG):
+            device_w, global_w, ghist, glast, gage = global_aggregate(
+                device_w, ghist, glast, gage)
 
         # ---- per-round metrics (same definitions as the legacy loop);
         # test accuracy is evaluated OUTSIDE the scan, batched over rounds.
@@ -918,10 +945,11 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
           inp.cons_energy, inp.cohort_change)
     final_carry, (globals_per_round, losses, deltas, clocks, energies) = \
         jax.lax.scan(global_round, carry0, xs)
-    accs = jax.lax.map(
-        lambda w: eval_accuracy(w, inp.test_x[inp.seed_idx],
-                                inp.test_y[inp.seed_idx], kernel_mode),
-        globals_per_round)
+    with jax.named_scope(telemetry.EVAL):
+        accs = jax.lax.map(
+            lambda w: eval_accuracy(w, inp.test_x[inp.seed_idx],
+                                    inp.test_y[inp.seed_idx], kernel_mode),
+            globals_per_round)
     if with_carry:
         return (accs, losses, deltas, clocks, energies), final_carry
     return accs, losses, deltas, clocks, energies

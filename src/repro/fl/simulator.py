@@ -29,13 +29,13 @@ reference for ``tests/test_engine_parity.py`` and the baseline for
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.configs.bhfl_cnn import BHFLSetting
 from repro.core import (baselines, consensus as _consensus, hieavg,
                         latency as lat, rng as rng_streams,
@@ -75,7 +75,6 @@ class RunResult:
     accuracy: np.ndarray          # [T] test accuracy after each global round
     loss: np.ndarray              # [T] mean local training loss
     grad_norm: np.ndarray         # [T] proxy: global-model round-to-round delta
-    wall_time: float
     sim_latency: float            # paper's latency model total (Sec. 5.1.4)
     blocks: int                   # committed blockchain blocks
     chain_valid: bool
@@ -92,6 +91,7 @@ class RunResult:
 class BHFLSimulator:
     """One BHFL deployment over the synthetic MNIST surrogate."""
 
+    @telemetry.span("sim.build")
     def __init__(self, setting: BHFLSetting = BHFLSetting(),
                  aggregator: str = "hieavg",
                  device_stragglers: str = "temporary",
@@ -385,6 +385,7 @@ class BHFLSimulator:
         return lat.total_latency(self.s.k_edge_rounds, self.lat)
 
     # ----------------------------------------------------------------- run
+    @telemetry.span("sim.run")
     def run(self, progress: bool = False) -> RunResult:
         """Run the deployment on the fully-jitted batched engine.
 
@@ -395,17 +396,18 @@ class BHFLSimulator:
         same instance is identical; the Raft chain, however, advances per
         call exactly like the legacy loop.
         """
-        t0 = time.time()
         inp = _engine.build_inputs(self)
         # donated entry: the freshly built hot input planes are handed to
         # the compiled run for buffer reuse (they are rebuilt per call, so
         # nothing else holds them)
-        accs, losses, deltas, clock, energy = _engine.run_engine_donated(
-            inp, aggregator=self.aggregator, normalize=self.normalize,
-            history_dtype=self.history_dtype, kernel_mode=self.kernel_mode)
-        accs, losses, deltas, clock, energy = (
-            np.asarray(accs), np.asarray(losses), np.asarray(deltas),
-            np.asarray(clock), np.asarray(energy))
+        with telemetry.span("run.execute"):
+            outs = _engine.run_engine_donated(
+                inp, aggregator=self.aggregator, normalize=self.normalize,
+                history_dtype=self.history_dtype,
+                kernel_mode=self.kernel_mode)
+        with telemetry.span("run.readback"):
+            accs, losses, deltas, clock, energy = (np.asarray(o)
+                                                   for o in outs)
         if progress:
             for t in range(1, self.s.t_global_rounds + 1):
                 if t % 10 == 0 or t == 1:
@@ -414,12 +416,13 @@ class BHFLSimulator:
                           f"clock={clock[t - 1]:.1f}s")
         return RunResult(
             accuracy=accs, loss=losses, grad_norm=deltas,
-            wall_time=time.time() - t0, sim_latency=self.paper_latency(),
+            sim_latency=self.paper_latency(),
             blocks=len(self.chain.blocks) - 1,
             chain_valid=self.chain.validate(), sim_clock=clock,
             sim_energy=energy)
 
     # ------------------------------------------------- checkpointed run
+    @telemetry.span("sim.run_checkpointed")
     def run_checkpointed(self, ckpt_dir: str, *, every: int = 10,
                          resume: bool = True,
                          progress: bool = False) -> RunResult:
@@ -448,7 +451,6 @@ class BHFLSimulator:
         """
         if every < 1:
             raise ValueError(f"every must be >= 1, got {every}")
-        t0 = time.time()
         T = self.s.t_global_rounds
         inp = _engine.build_inputs(self)
         carry = _engine.init_engine_carry(inp, self.history_dtype)
@@ -467,26 +469,27 @@ class BHFLSimulator:
                     print(f"  resumed from checkpoint @ t={t_done}")
         while t_done < T:
             t1 = min(t_done + every, T)
-            seg = _engine.run_engine_chunk(
-                _engine.slice_rounds(inp, t_done, t1), carry,
-                jnp.int32(t_done), aggregator=self.aggregator,
-                normalize=self.normalize, history_dtype=self.history_dtype,
-                kernel_mode=self.kernel_mode)
-            (acc, loss, delta, clock, energy), carry = seg
-            for k, v in zip(keys, (acc, loss, delta, clock, energy)):
-                outs[k] = np.concatenate([outs[k],
-                                          np.asarray(v, np.float32)])
+            with telemetry.span("run.segment"):
+                seg, carry = _engine.run_engine_chunk(
+                    _engine.slice_rounds(inp, t_done, t1), carry,
+                    jnp.int32(t_done), aggregator=self.aggregator,
+                    normalize=self.normalize,
+                    history_dtype=self.history_dtype,
+                    kernel_mode=self.kernel_mode)
+                for k, v in zip(keys, seg):
+                    outs[k] = np.concatenate([outs[k],
+                                              np.asarray(v, np.float32)])
             t_done = t1
-            _ckpt.save_checkpoint(ckpt_dir, t_done,
-                                  {"carry": carry, "outs": outs},
-                                  metadata={"t": t_done})
+            with telemetry.span("run.checkpoint"):
+                _ckpt.save_checkpoint(ckpt_dir, t_done,
+                                      {"carry": carry, "outs": outs},
+                                      metadata={"t": t_done})
             if progress:
                 print(f"  t={t_done:3d} acc={outs['accuracy'][-1]:.4f} "
                       f"clock={outs['clock'][-1]:.1f}s  [checkpointed]")
         return RunResult(
             accuracy=outs["accuracy"], loss=outs["loss"],
-            grad_norm=outs["delta"], wall_time=time.time() - t0,
-            sim_latency=self.paper_latency(),
+            grad_norm=outs["delta"], sim_latency=self.paper_latency(),
             blocks=len(self.chain.blocks) - 1,
             chain_valid=self.chain.validate(), sim_clock=outs["clock"],
             sim_energy=outs["energy"])
@@ -509,7 +512,6 @@ class BHFLSimulator:
                 "stochastic fault injection (repro.fl.faults) runs on the "
                 "engine path only; use run()")
         s = self.s
-        t0 = time.time()
         batch_rng = rng_streams.stream_rng(self.seed, "batches")
         # device-resident test set for the per-round eval (self.test_x is
         # a numpy view; re-committing it every round would tax the loop)
@@ -594,8 +596,7 @@ class BHFLSimulator:
 
         return RunResult(
             accuracy=np.asarray(accs), loss=np.asarray(losses),
-            grad_norm=np.asarray(deltas), wall_time=time.time() - t0,
-            sim_latency=self.paper_latency(),
+            grad_norm=np.asarray(deltas), sim_latency=self.paper_latency(),
             blocks=len(self.chain.blocks) - 1,
             chain_valid=self.chain.validate())
 

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from repro import telemetry
 from repro.configs.bhfl_cnn import BHFLSetting
 from repro.fl.engine import (AGG_SEL, SHARED_DATA_FIELDS, EngineInputs,
                              build_inputs, merge_inputs, run_engine,
@@ -204,22 +205,23 @@ def _measured_step_time(d: int, geom: tuple) -> float:
     """
     times = _STEP_TIME_CACHE.setdefault(geom, {})
     if d not in times:
-        hw, bs, c1, c2, n_classes, kernel_mode = geom
-        specs = cnn_specs(hw, 1, n_classes, c1, c2)
-        params = {k: jnp.zeros((d,) + sp.shape, jnp.float32)
-                  for k, sp in specs.items()}
-        images = jnp.zeros((d, 1, bs, hw, hw, 1), jnp.float32)
-        labels = jnp.zeros((d, 1, bs), jnp.int32)
-        lr = jnp.float32(0.01)
-        fn = jax.jit(functools.partial(train_epoch_body,
-                                       kernel_mode=kernel_mode))
-        jax.block_until_ready(fn(params, images, labels, lr))  # compile
-        best = float("inf")
-        for _ in range(2):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(params, images, labels, lr))
-            best = min(best, time.perf_counter() - t0)
-        times[d] = best
+        with telemetry.span("sweep.step_probe"):
+            hw, bs, c1, c2, n_classes, kernel_mode = geom
+            specs = cnn_specs(hw, 1, n_classes, c1, c2)
+            params = {k: jnp.zeros((d,) + sp.shape, jnp.float32)
+                      for k, sp in specs.items()}
+            images = jnp.zeros((d, 1, bs, hw, hw, 1), jnp.float32)
+            labels = jnp.zeros((d, 1, bs), jnp.int32)
+            lr = jnp.float32(0.01)
+            fn = jax.jit(functools.partial(train_epoch_body,
+                                           kernel_mode=kernel_mode))
+            jax.block_until_ready(fn(params, images, labels, lr))  # compile
+            best = float("inf")
+            for _ in range(2):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(params, images, labels, lr))
+                best = min(best, time.perf_counter() - t0)
+            times[d] = best
     mono = max(t for dd, t in times.items() if dd <= d)
     return mono * (1.0 + 1e-6 * d)
 
@@ -474,6 +476,7 @@ class SweepResult:
         return int(np.argmin(times)), times
 
 
+@telemetry.span("sweep.plan")
 def plan_sweep(setting: BHFLSetting, seeds=(0,), *,
                overrides: Optional[list] = None,
                aggregator: str = "hieavg",
@@ -775,43 +778,44 @@ def execute_plan(plan: SweepPlan, *, mesh=None, placement: str = "auto",
                 "this SweepPlan's bucket inputs were consumed by a "
                 "previous donated execute_plan/run_plan; build a fresh "
                 "plan, or run with donate=False to keep a plan re-runnable")
-        hot, shared = split_inputs(b.inputs, shared_seed_idx=seed_shared)
-        with warnings.catch_warnings():
-            # expected under donation: the engine's [P, T] outputs are far
-            # smaller than the stacked input planes, so XLA rarely finds
-            # an input-output alias — the reference release below is the
-            # real win
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            if spec == PartitionSpec():
-                outs = _vmap_runner(plan.aggregator, plan.normalize,
-                                    plan.history_dtype, plan.kernel_mode,
-                                    donate, point_batch)(hot, shared)
-            else:
-                outs = _sharded_runner(plan.aggregator, plan.normalize,
-                                       plan.history_dtype, mesh, spec,
-                                       plan.kernel_mode, donate,
-                                       point_batch)(hot, shared)
-        if donate:
-            # the compiled call has consumed the stacked planes: drop the
-            # plan's reference so it stops pinning the caller-side copy
-            # (the shared data plane stays — every bucket and same-seed
-            # point aliases it).  Only after a SUCCESSFUL dispatch: a
-            # bucket that failed to compile/run stays intact, so the plan
-            # remains retryable
-            b.inputs = None
-        del hot
-        a, l, g, c, en = (np.asarray(o) for o in outs)
-        ids = np.asarray(b.point_ids)
-        Tb = a.shape[1]
-        acc[ids, :Tb] = a
-        acc[ids, Tb:] = a[:, -1:]
-        loss[ids, :Tb] = l
-        gn[ids, :Tb] = g
-        clock[ids, :Tb] = c
-        clock[ids, Tb:] = c[:, -1:]
-        energy[ids, :Tb] = en
-        energy[ids, Tb:] = en[:, -1:]
+        with telemetry.span("sweep.bucket"):
+            hot, shared = split_inputs(b.inputs, shared_seed_idx=seed_shared)
+            with warnings.catch_warnings():
+                # expected under donation: the engine's [P, T] outputs are far
+                # smaller than the stacked input planes, so XLA rarely finds
+                # an input-output alias — the reference release below is the
+                # real win
+                warnings.filterwarnings(
+                    "ignore", message="Some donated buffers were not usable")
+                if spec == PartitionSpec():
+                    outs = _vmap_runner(plan.aggregator, plan.normalize,
+                                        plan.history_dtype, plan.kernel_mode,
+                                        donate, point_batch)(hot, shared)
+                else:
+                    outs = _sharded_runner(plan.aggregator, plan.normalize,
+                                           plan.history_dtype, mesh, spec,
+                                           plan.kernel_mode, donate,
+                                           point_batch)(hot, shared)
+            if donate:
+                # the compiled call has consumed the stacked planes: drop the
+                # plan's reference so it stops pinning the caller-side copy
+                # (the shared data plane stays — every bucket and same-seed
+                # point aliases it).  Only after a SUCCESSFUL dispatch: a
+                # bucket that failed to compile/run stays intact, so the plan
+                # remains retryable
+                b.inputs = None
+            del hot
+            a, l, g, c, en = (np.asarray(o) for o in outs)
+            ids = np.asarray(b.point_ids)
+            Tb = a.shape[1]
+            acc[ids, :Tb] = a
+            acc[ids, Tb:] = a[:, -1:]
+            loss[ids, :Tb] = l
+            gn[ids, :Tb] = g
+            clock[ids, :Tb] = c
+            clock[ids, Tb:] = c[:, -1:]
+            energy[ids, :Tb] = en
+            energy[ids, Tb:] = en[:, -1:]
     return acc, loss, gn, clock, energy
 
 

@@ -9,8 +9,14 @@ kernels that outgrow VMEM.
 
 The topology is described inside a fixture (never at import), so only the
 worker that runs this file loads the TPU library.
+
+The last case compiles the engine's one-round program itself, at a tiny
+size, and checks that each Pallas call of it lies under one round phase
+(``repro.telemetry.PHASES``): what a device trace's phase metrics read.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,3 +119,37 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
             for s, dt in zip(shapes, dtypes)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_pallas_calls_lie_under_one_phase(one_chip,
+                                                 no_persistent_cache):
+    """Each Pallas custom call of the one-round ``run_engine_chunk``,
+    compiled for the described chip, names exactly one round phase in its
+    ``op_name``.  The lowered text cannot show it: there each kernel sits
+    in a function of its own, whose locations name only the kernel."""
+    from repro import telemetry
+    from repro.configs.bhfl_cnn import REDUCED
+    from repro.fl import BHFLSimulator, engine
+
+    setting = dataclasses.replace(REDUCED, t_global_rounds=3, n_edges=2,
+                                  j_per_edge=3, image_hw=8)
+    sim = BHFLSimulator(setting, n_train=120, n_test=40, steps_per_epoch=2)
+    inp = engine.build_inputs(sim)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (engine.slice_rounds(inp, 0, 1), engine.init_engine_carry(inp),
+         jnp.int32(0)))
+    text = engine.run_engine_chunk.lower(
+        *args, kernel_mode="pallas").compile().as_text()
+
+    def phases(line):
+        op = re.search(r'op_name="([^"]*)"', line)
+        return [p for p in telemetry.PHASES if op and p in op.group(1)]
+
+    lines = text.splitlines()
+    calls = [phases(ln) for ln in lines
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(len(ph) == 1 for ph in calls), calls
+    assert {ph[0] for ph in calls} == set(telemetry.PHASES)
+    # XLA may merge instructions and their op_names; none names two phases
+    assert all(len(phases(ln)) <= 1 for ln in lines)
