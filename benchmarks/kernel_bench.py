@@ -9,7 +9,6 @@ Two sections:
     difference), and an allclose check.  Rows:
 
       - ``hieavg_agg``     — warm edge aggregation (estimate+mix+history),
-      - ``conv3x3``        — im2col matmul with fused bias+ReLU epilogue,
       - ``eval_head``      — logits → argmax → correct-count, one pass,
       - ``coef_agg_pair``  — the generalized coefficient aggregate (pair
         form: the delayed-gradient fill + weighted mean in one pass).
@@ -27,9 +26,9 @@ Two sections:
 
   The JSON carries the ``padded_flop_frac``-style kernel-plane coverage
   block (``fused_phase_coverage``): which engine round phases run fused
-  under the measured mode, and under a fused mode — conv fwd/bwd, SGD,
-  warm+cold aggregation, fedavg, delayed-grad, and the eval head, i.e.
-  the whole round.
+  under the measured mode, and under a fused mode — conv fwd/bwd (XLA's
+  own convolution, no Pallas kernel), SGD, warm+cold aggregation, fedavg,
+  delayed-grad, and the eval head, i.e. the whole round.
 
   PYTHONPATH=src python -m benchmarks.run --only kernels --emit-json
 """
@@ -70,18 +69,6 @@ def hbm_traffic_gb(n: int, l: int, bytes_per: int = 4) -> tuple[float, float]:
     xla = 7 * leaf
     fused = (3 * leaf) + (2 * leaf + l * bytes_per)
     return xla / 1e9, fused / 1e9
-
-
-def conv_traffic_gb(m: int, k: int, n: int,
-                    bytes_per: int = 4) -> tuple[float, float]:
-    """(XLA, fused) HBM bytes for the conv matmul + bias + ReLU.
-
-    Both paths read the im2col cols ``[M, K]`` and weights once; XLA then
-    writes the matmul result and re-reads/re-writes it for the separate
-    bias-add + ReLU (3 output passes), the fused epilogue writes it once.
-    """
-    cols, out = m * k * bytes_per, m * n * bytes_per
-    return (cols + 3 * out) / 1e9, (cols + out) / 1e9
 
 
 def eval_traffic_gb(m: int, f: int, c: int,
@@ -148,23 +135,6 @@ def _micro_rows(csv: Csv) -> list[dict]:
         xla_gb, fused_gb = hbm_traffic_gb(n, l)
         rows.append(_row(csv, "hieavg_agg", n, l, xla_gb, fused_gb,
                          xla_ms, fused_ms, ok))
-
-    # fused conv3x3 + bias + ReLU (the training fwd hot-spot)
-    ks = jax.random.split(jax.random.key(1), 3)
-    b_, hw, cin, cout = 16, 28, 8, 16
-    x = jax.random.normal(ks[0], (b_, hw, hw, cin))
-    w3 = jax.random.normal(ks[1], (3, 3, cin, cout)) * 0.1
-    bb = jax.random.normal(ks[2], (cout,)) * 0.1
-    xla_conv = jax.jit(ref.conv3x3_bias_relu_ref)
-    fused_conv = jax.jit(ops.conv3x3_bias_relu)
-    xla_ms, fused_ms = _pair_ms(lambda: xla_conv(x, w3, bb),
-                                lambda: fused_conv(x, w3, bb))
-    ok = bool(jnp.allclose(xla_conv(x, w3, bb), fused_conv(x, w3, bb),
-                           atol=1e-4))
-    m = b_ * hw * hw
-    xla_gb, fused_gb = conv_traffic_gb(m, 9 * cin, cout)
-    rows.append(_row(csv, "conv3x3", m, 9 * cin * cout, xla_gb, fused_gb,
-                     xla_ms, fused_ms, ok))
 
     # fused eval head (logits -> argmax -> count, one pass)
     ks = jax.random.split(jax.random.key(2), 4)

@@ -85,9 +85,10 @@ def train_epoch_body(params: PyTree, images: jnp.ndarray,
     (new stacked params, mean loss per device [D]).
 
     scan(vmap(step)) rather than vmap(scan): one fused all-device matmul per
-    step instead of D separate small ones.  The engine trains with the
-    im2col conv (``cnn_loss_fast``); the legacy reference loop keeps the
-    shifted-sum conv (same math, different summation order).
+    step instead of D separate small ones.  The engine trains with
+    ``cnn_loss_fast``: the im2col conv on the CPU, XLA's own convolution
+    on a TPU; the legacy reference loop keeps the shifted-sum conv (same
+    math, different summation order).
 
     ``step_ok`` (optional, [steps] f32 of 0/1): per-step validity for the
     sweep fabric, whose grid points may disagree on steps-per-epoch.  A
@@ -99,8 +100,8 @@ def train_epoch_body(params: PyTree, images: jnp.ndarray,
     routes the inner SGD update through ``kernels.dispatch.sgd_update`` —
     the fused one-pass kernel on accelerators, the original ``tree.map``
     on the XLA path — and, when ``loss_fn`` is None (the default), the
-    conv blocks inside the loss through the fused conv kernel
-    (``cnn_loss_fast(kernel_mode=...)``).  An explicit ``loss_fn``
+    conv blocks inside the loss through ``kernels.dispatch`` (XLA's own
+    convolution under a fused mode; ``cnn_loss_fast(kernel_mode=...)``).  An explicit ``loss_fn``
     (``run_legacy``'s shifted-sum ``cnn_loss``) is used as-is.  The
     padded-step mask folds into the kernel's scale (0 → exact identity)
     so padding stays a numeric no-op on every path.
@@ -689,7 +690,8 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
     edge/global aggregations, the cold-boot means, the FedAvg and
     delayed-gradient aggregates (the "switched" set), and the post-scan
     eval head.  ``"auto"`` resolves to the fused Pallas kernels on
-    TPU and the pure-XLA reference on CPU (zero overhead);
+    TPU (the conv as XLA's own convolution) and the pure-XLA reference
+    on CPU (zero overhead);
     ``"interpret"`` forces the Pallas interpreter (the CPU validation
     path the parity tests pin); ``"xla"`` forces the reference.  Only
     the legacy ``t_fedavg``/``d_fedavg`` baselines and the tiny history

@@ -55,7 +55,9 @@ PyTree = Any
 
 #: The engine round phases with a fused kernel, in round order.  Under a
 #: fused mode (``pallas``/``interpret``) every phase listed here runs in
-#: a Pallas kernel; under ``xla`` all run the pure-jnp reference paths.
+#: a Pallas kernel, but the conv, which runs XLA's own convolution fused
+#: with its bias and ReLU; under ``xla`` all run the pure-jnp reference
+#: paths.
 #: (``t_fedavg``/``d_fedavg`` — legacy baselines outside the switched
 #: set — and the tiny history-bookkeeping updates stay XLA by design.)
 ROUND_PHASES = ("train_conv_fwd_bwd", "sgd_update", "warm_edge_aggregate",
@@ -180,8 +182,15 @@ def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                       mode: str = "auto") -> jnp.ndarray:
     """The CNN conv block ``relu(conv3x3_same(x, w) + b)``.
 
-    The fused path runs the im2col matmul with bias+ReLU epilogue (and
-    both backward matmuls) in Pallas; ``xla`` is the engine's original
+    x: [..., H, W, Cin]; w: [3, 3, Cin, Cout]; b: [Cout].  The fused
+    modes run XLA's own convolution, which XLA fuses with the bias and
+    ReLU and differentiates itself: on a TPU it never writes the 9x-wide
+    im2col patches to HBM, and under the engine's ``vmap`` over devices
+    (and a sweep's over points) the per-device weights become grouped
+    convolutions.  Leading dims beyond one batch dim fold into N.  As in
+    ``ref.conv3x3_bias_relu_ref``, it accumulates and adds the bias in
+    f32 and casts the output back to ``x.dtype`` (a no-op in f32).
+    ``xla`` (the CPU default) is the engine's original
     ``_conv3x3_same_im2col`` einsum + separate bias/ReLU, bit-identical
     to what ``cnn_apply_fast`` always did.
     """
@@ -189,8 +198,13 @@ def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
     if mode == "xla":
         from repro.models.cnn import _conv3x3_same_im2col
         return jax.nn.relu(_conv3x3_same_im2col(x, w) + b)
-    from . import ops
-    return ops.conv3x3_bias_relu(x, w, b, interpret=_interpret(mode))
+    f32 = jnp.float32
+    y = jax.lax.conv_general_dilated(
+        x.reshape((-1,) + x.shape[-3:]), w, (1, 1), "SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=f32)
+    y = jax.nn.relu(y + b.astype(f32)).astype(x.dtype)
+    return y.reshape(x.shape[:-1] + y.shape[-1:])
 
 
 def eval_head(feats: jnp.ndarray, wmat: jnp.ndarray, bias: jnp.ndarray,

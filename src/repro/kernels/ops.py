@@ -20,12 +20,13 @@ pytrees, dispatching each leaf (flattened to ``[n, L]``) through the fused
 ``fused_sgd_update`` is the train-step inner loop: the masked SGD update
 ``w − (lr·ok)·g`` in one pass per leaf (``kernels.sgd_update``).
 
-``conv3x3_bias_relu`` / ``eval_head`` (re-exported from their kernel
-modules) and the ``fused_coef_aggregate`` pair close the rest of the
-round: the CNN conv block with its fused bias+ReLU epilogue and custom
-VJP, the classifier-head correct-count eval, and the generalized
-coefficient aggregate shared by the cold-boot means, FedAvg and the
-delayed-gradient mix (zero-coefficient padded slots stay exact no-ops).
+``eval_head`` (re-exported from its kernel module) and the
+``fused_coef_aggregate`` pair close the rest of the round: the
+classifier-head correct-count eval, and the generalized coefficient
+aggregate shared by the cold-boot means, FedAvg and the delayed-gradient
+mix (zero-coefficient padded slots stay exact no-ops).  The CNN conv
+block has no kernel: ``dispatch.conv3x3_bias_relu`` runs XLA's own
+convolution.
 
 ``flash_attention`` is the multi-head GQA front-end of the single-head
 kernel: batch, kv-head and group dims are vmapped (Pallas prepends them as
@@ -47,7 +48,6 @@ import jax.numpy as jnp
 
 from repro.core.hieavg import History
 from .coef_agg import coef_agg, coef_agg_pair
-from .conv3x3 import conv3x3_bias_relu
 from .dispatch import default_interpret
 from .eval_head import eval_head
 from .flash_attention import flash_attention_1h

@@ -27,7 +27,12 @@ def _conv3x3_same(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 
     Pure dot-products instead of lax.conv: XLA:CPU's batched conv gradients
     (batch_group_count under vmap) are orders of magnitude slower than the
-    equivalent matmul, and the FL simulator vmaps over dozens of devices.
+    equivalent matmul, and the FL simulator vmaps over dozens of devices,
+    each with its own weights.  The vmapped gradient at 25 devices x 32 x
+    28x28x32, on a Xeon host: 19.6 s for lax.conv against 0.94 s for the
+    im2col einsum with per-device weights; with weights shared by all
+    devices lax.conv wins, 0.53 s against 1.21 s.  A TPU runs the
+    per-device lax.conv well: ``kernels.dispatch`` runs it there.
     x: [..., H, W, Cin]; w: [3, 3, Cin, Cout].
     """
     h, wd = x.shape[-3], x.shape[-2]
@@ -113,8 +118,9 @@ def _apply(params: dict, images: jnp.ndarray, conv) -> jnp.ndarray:
 
 def _features_fused(params: dict, images: jnp.ndarray, kernel_mode: str
                     ) -> jnp.ndarray:
-    """Pooled/flattened features with the conv blocks kernel-routed
-    (``kernels.dispatch.conv3x3_bias_relu`` — fused matmul+bias+ReLU)."""
+    """Pooled/flattened features with the conv blocks routed through
+    ``kernels.dispatch.conv3x3_bias_relu`` (XLA's own convolution, fused
+    with its bias and ReLU)."""
     from repro.kernels import dispatch as _kd
     x = images
     for w, b in ((params["conv1"], params["b1"]),
@@ -134,8 +140,9 @@ def cnn_apply_fast(params: dict, images: jnp.ndarray,
 
     ``kernel_mode`` (resolved or ``"auto"``) routes the conv blocks:
     ``"xla"`` (the default, bit-identical to what this function always
-    did) keeps the plain im2col einsum; the fused modes run them through
-    the Pallas conv kernel.  The engine threads its resolved mode here.
+    did) keeps the plain im2col einsum; the fused modes (the TPU's) run
+    them as XLA's own convolution, through ``kernels.dispatch``.  The
+    engine threads its resolved mode here.
     """
     from repro.kernels import dispatch as _kd
     mode = _kd.resolve_kernel_mode(kernel_mode)
@@ -175,11 +182,11 @@ def cnn_accuracy(params: dict, images: jnp.ndarray, labels: jnp.ndarray
 
 def cnn_correct_fast(params: dict, images: jnp.ndarray, labels: jnp.ndarray,
                      kernel_mode: str = "xla") -> jnp.ndarray:
-    """Int32 count of correct predictions on the im2col forward (the
-    engine's eval path); a label of -1 never counts.
+    """Int32 count of correct predictions on the ``cnn_apply_fast``
+    forward (the engine's eval path); a label of -1 never counts.
 
     Under a fused ``kernel_mode`` the whole eval runs kernel-routed: conv
-    blocks through the fused conv kernel, then the classifier head as one
+    blocks as XLA's own convolution, then the classifier head as one
     logits → argmax → correct-count pass (``kernels.dispatch.eval_head``)
     — the logits buffer never materializes.
     """

@@ -31,7 +31,6 @@ from repro.fl.engine import (SHARED_DATA_FIELDS, run_engine,
 from repro.kernels import dispatch as kd
 from repro.kernels.coef_agg import TILE as CTILE
 from repro.kernels.coef_agg import coef_agg, coef_agg_pair
-from repro.kernels.conv3x3 import conv3x3_bias_relu
 from repro.kernels.eval_head import eval_head
 from repro.kernels.ops import (fused_edge_aggregate_batched,
                                fused_mix_and_update)
@@ -201,20 +200,26 @@ def test_fused_global_matches_core_traced_weights():
 
 
 # ------------------------------------------------- conv / eval / coef oracles
+def _conv_block(x, w, b):
+    """The TPU path's conv block (XLA's own convolution), run on the CPU."""
+    return kd.conv3x3_bias_relu(x, w, b, mode="interpret")
+
+
 @pytest.mark.kernel_oracle
-@pytest.mark.parametrize("b,hw,cin,cout", [
-    (1, 5, 1, 3),     # M = 25 < TILE_M, single ragged tile
-    (2, 12, 4, 8),    # M = 288: one full tile + tail
-    (2, 16, 3, 7),    # M = 512: exact tile multiple, odd cout
+@pytest.mark.parametrize("lead,hw,cin,cout", [
+    ((1,), 5, 1, 3),     # one image, one input channel
+    ((2,), 12, 4, 8),
+    ((2,), 16, 3, 7),    # odd cout
+    ((2, 3), 7, 2, 5),   # two leading batch dims, folded into N
 ])
-def test_conv3x3_matches_ref_on_tile_tails(b, hw, cin, cout):
-    """The fused conv epilogue across M-tile tails (B·H·W not a multiple
-    of the 256-row tile) and non-multiple-of-anything channel counts."""
+def test_conv3x3_matches_ref_on_odd_shapes(lead, hw, cin, cout):
+    """The conv block against the im2col oracle on odd spatial extents,
+    channel counts and leading batch dims."""
     ks = jax.random.split(jax.random.key(0), 3)
-    x = jax.random.normal(ks[0], (b, hw, hw, cin))
+    x = jax.random.normal(ks[0], lead + (hw, hw, cin))
     w = jax.random.normal(ks[1], (3, 3, cin, cout)) * 0.3
     bb = jax.random.normal(ks[2], (cout,)) * 0.3
-    got = conv3x3_bias_relu(x, w, bb, interpret=True)
+    got = _conv_block(x, w, bb)
     ref = conv3x3_bias_relu_ref(x, w, bb)
     assert got.shape == ref.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
@@ -222,9 +227,8 @@ def test_conv3x3_matches_ref_on_tile_tails(b, hw, cin, cout):
 
 @pytest.mark.kernel_oracle
 def test_conv3x3_grads_match_ref():
-    """The custom VJPs: dx (the col2im of ``models.cnn.im2col3x3``), dw
-    and db (the Pallas backward matmuls) against the pure-jnp reference's
-    autodiff."""
+    """XLA's transpose of the convolution: dx, dw and db against the
+    pure-jnp reference's autodiff."""
     ks = jax.random.split(jax.random.key(1), 4)
     x = jax.random.normal(ks[0], (2, 9, 9, 3))
     w = jax.random.normal(ks[1], (3, 3, 3, 5)) * 0.3
@@ -234,9 +238,7 @@ def test_conv3x3_grads_match_ref():
     def loss(fn):
         return lambda x, w, b: jnp.sum(fn(x, w, b) * dy)
 
-    gx, gw, gb = jax.grad(
-        loss(lambda x, w, b: conv3x3_bias_relu(x, w, b, interpret=True)),
-        argnums=(0, 1, 2))(x, w, b)
+    gx, gw, gb = jax.grad(loss(_conv_block), argnums=(0, 1, 2))(x, w, b)
     rx, rw, rb = jax.grad(loss(conv3x3_bias_relu_ref),
                           argnums=(0, 1, 2))(x, w, b)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), atol=1e-4)
@@ -289,13 +291,14 @@ def test_eval_accuracy_in_chunks_equals_whole_set(mode, chunk, monkeypatch):
 
 @pytest.mark.kernel_oracle
 def test_conv3x3_bf16_storage():
-    """bf16 operands: f32 tile math, output cast back to bf16 — matching
-    the reference's f32-accumulate-then-cast within bf16 rounding."""
+    """bf16 operands: f32 accumulation and bias, output cast back to bf16
+    — matching the reference's f32-accumulate-then-cast within bf16
+    rounding."""
     ks = jax.random.split(jax.random.key(2), 3)
     x = jax.random.normal(ks[0], (2, 8, 8, 4), jnp.bfloat16)
     w = (jax.random.normal(ks[1], (3, 3, 4, 6)) * 0.3).astype(jnp.bfloat16)
     b = (jax.random.normal(ks[2], (6,)) * 0.3).astype(jnp.bfloat16)
-    got = conv3x3_bias_relu(x, w, b, interpret=True)
+    got = _conv_block(x, w, b)
     ref = conv3x3_bias_relu_ref(x, w, b)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -439,8 +442,9 @@ def test_dispatch_baseline_aggregates_match_references():
 
 @pytest.mark.kernel_oracle
 def test_dispatch_conv_eval_interpret_matches_xla_branch():
-    """The two train/eval dispatch entries: interpret vs the xla branch
-    (which is the engine's original conv/eval chain, bit-for-bit)."""
+    """The two train/eval dispatch entries: interpret (XLA's convolution,
+    the eval-head kernel) vs the xla branch (the engine's original
+    im2col conv and eval chain, bit-for-bit)."""
     ks = jax.random.split(jax.random.key(10), 3)
     x = jax.random.normal(ks[0], (2, 8, 8, 3))
     w = jax.random.normal(ks[1], (3, 3, 3, 6)) * 0.3

@@ -1,11 +1,14 @@
-"""Every Pallas kernel of the engine's main path compiles for a TPU v5e.
+"""Every kernel of the engine's main path compiles for a TPU v5e.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  Shapes are the paper's DEFAULT widths (28x28 inputs, 32/64
 conv channels, N = J = 5 edges x devices, batch 32, 1000 test images),
 batched the way the engine calls each kernel.  This catches what the
 Pallas interpreter cannot: block shapes the TPU tiling refuses and
-kernels that outgrow VMEM.
+kernels that outgrow VMEM.  The CNN conv block is no Pallas kernel but
+XLA's own convolution: its cases check that it compiles to one with no
+custom call around it, and that a whole train step stays within a
+temporary-memory budget that the 9x im2col patch buffer would break.
 
 The topology is described inside a fixture (never at import), so only the
 worker that runs this file loads the TPU library.
@@ -22,8 +25,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import dispatch
 from repro.kernels.coef_agg import coef_agg, coef_agg_pair
-from repro.kernels.conv3x3 import conv3x3_bias_relu
 from repro.kernels.eval_head import eval_head
 from repro.kernels.hieavg_agg import hieavg_agg
 from repro.kernels.sgd_update import sgd_update
@@ -64,29 +67,11 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _conv(cin, cout):
-    def fwd(x, w, b):
-        return jax.vmap(functools.partial(conv3x3_bias_relu,
-                                          interpret=False),
-                        in_axes=(0, None, None))(x, w, b)
-    return fwd, ((D, B, HW, HW, cin), (3, 3, cin, cout), (cout,))
-
-
-def _conv_grad(cin, cout):
-    fwd, shapes = _conv(cin, cout)
-    return jax.grad(lambda x, w, b: jnp.sum(fwd(x, w, b)),
-                    argnums=(0, 1, 2)), shapes
-
-
 def _per_edge(fn):
     return jax.vmap(functools.partial(fn, interpret=False))
 
 
 CASES = {
-    "conv3x3_fwd_cin1": lambda: _conv(1, C1),
-    "conv3x3_bwd_cin1": lambda: _conv_grad(1, C1),
-    "conv3x3_fwd_cin32": lambda: _conv(C1, C2),
-    "conv3x3_bwd_cin32": lambda: _conv_grad(C1, C2),
     "sgd_update": lambda: (
         functools.partial(sgd_update, interpret=False),
         ((D, L_DENSE), (D, L_DENSE), ())),
@@ -119,6 +104,61 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
             for s, dt in zip(shapes, dtypes)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _conv(cin, cout):
+    """The conv block vmapped over devices, each with its own weights, as
+    the engine's train step runs it on a TPU."""
+    def fwd(x, w, b):
+        return jax.vmap(functools.partial(dispatch.conv3x3_bias_relu,
+                                          mode="pallas"))(x, w, b)
+    return fwd, ((D, B, HW, HW, cin), (D, 3, 3, cin, cout), (D, cout))
+
+
+def _conv_grad(cin, cout):
+    fwd, shapes = _conv(cin, cout)
+    return jax.grad(lambda x, w, b: jnp.sum(fwd(x, w, b)),
+                    argnums=(0, 1, 2)), shapes
+
+
+CONV_CASES = {
+    "conv3x3_fwd_cin1": lambda: _conv(1, C1),
+    "conv3x3_bwd_cin1": lambda: _conv_grad(1, C1),
+    "conv3x3_fwd_cin32": lambda: _conv(C1, C2),
+    "conv3x3_bwd_cin32": lambda: _conv_grad(C1, C2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CONV_CASES))
+def test_conv_block_compiles_to_xla_convolution_for_v5e(
+        name, one_chip, no_persistent_cache):
+    fn, shapes = CONV_CASES[name]()
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "convolution(" in text
+    assert "tpu_custom_call" not in text
+
+
+def test_train_step_temporaries_fit_budget(one_chip, no_persistent_cache):
+    """One local SGD step of sec6's 25 devices x batch 32 at DEFAULT
+    widths, per-device weights, as the engine compiles it for the chip:
+    its temporaries stay under 2.5 GB (1.54 GB with XLA's convolution;
+    the im2col patches around a Pallas matmul took 5.64 GB)."""
+    from repro.fl.engine import train_epoch_body
+    from repro.models import cnn_specs, init_from_specs
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda: init_from_specs(cnn_specs(), jax.random.key(0)))
+    args = (jax.tree.map(lambda a: spec((D,) + a.shape, a.dtype), params),
+            spec((D, 1, B, HW, HW, 1)), spec((D, 1, B), jnp.int32),
+            spec(()))
+    step = functools.partial(train_epoch_body, kernel_mode="pallas")
+    compiled = jax.jit(step).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 def test_engine_pallas_calls_lie_under_one_phase(one_chip,
