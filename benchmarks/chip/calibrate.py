@@ -4,9 +4,9 @@
         --seeds 101 102 103 [--program] [--variants control half_batch ...]
 
 For each seed this builds the cell's deployment as a run's set-up does,
-runs the plain reference (float32, the configuration's precision) over
-the checked rounds, and puts in the program's place, at the cell's own
-size:
+runs the plain reference (float32, the configuration's precision) of the
+client model the configuration names over the checked rounds, and puts
+in the program's place, at the cell's own size:
 
 * ``program``: the window's compiled one-round program, as a run's set-up
   drives it (the lower readings come from sound runs of this);
@@ -58,26 +58,25 @@ def main(argv=None) -> int:
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     c = cells.Cell.named(args.workload)
-    ref = run.load_module(run.HERE / "references"
-                          / f"{c.config['reference']}.py")
+    ref, model = run.load_models(c)
     compiled = None
     for seed in args.seeds:
-        p = run.prepare(c, seed, ref)
+        p = run.prepare(c, seed, model)
         planes, w0, checked = p.planes, p.w0, p.checked
         prog = None
         if args.program:
             compiled = compiled or run.compile_round(p)
-            prog, _ = run.first_rounds(compiled, p)
+            prog, _ = run.first_rounds(compiled, p, model)
         del p
         gc.collect()
         t = time.perf_counter()
-        base = ref.run(c.config, planes, w0, checked)
+        base = ref.run(model, c.config, planes, w0, checked)
         secs = {"reference": time.perf_counter() - t}
         stand_ins = {"program": prog} if prog else {}
         for v in args.variants:
             t = time.perf_counter()
             stand_ins[v] = ref.run(
-                c.config, planes, w0, checked,
+                model, c.config, planes, w0, checked,
                 dtype=jnp.bfloat16 if v == "control" else jnp.float32,
                 fault=None if v == "control" else v)
             secs[v] = time.perf_counter() - t

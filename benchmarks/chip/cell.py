@@ -5,13 +5,24 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric sits in a file of its own, found by the name ``BENCHMARK.json``
 gives it:
 
-    configs/<config>.json     sizes, simulator arguments, reference module
+    configs/<config>.json     sizes, simulator arguments, and the names of
+                              its client model and its reference
+    models/<model>.py         the client model, written plainly: the one
+                              file that reads the model's sizes
     traffic/<traffic>.json    aggregator, straggler modes and rates, faults
     limits/<workload>.json    the limit of each number ``correct`` compares
     metrics/<metric>.py       one reader per per-layer metric
-    references/<name>.py      the plain reference a configuration names
+    references/<name>.py      the plain reference of the FL rounds, which
+                              takes the model module as an argument
 
-so a new cell is a new data file or two plus one ``workloads`` entry.
+so a new cell is a new data file or two plus one ``workloads`` entry, and
+a new client model one more module.  Every model module keeps one
+contract (written out in ``models/cnn.py``):
+
+    param_shapes(setting), init_params(config, seed), loss(p, x, y),
+    test_count(p, x, y), n_eval(planes), train_flops_per_sample(setting)
+
+and may add counts of its kernels' work, such as ``conv_work``.
 """
 from __future__ import annotations
 

@@ -1,25 +1,24 @@
 """Edge and global aggregation's share of its roofline: the least time of
-the window's warm HieAvg mixes (``work.aggregate_work``, bytes-bound) over
-the device time of the ops that do them: the fused ``hieavg_agg`` and
-``coef_agg`` kernels, or the XLA aggregation of ``core.hieavg`` that
-stands in for them.  Moves ``samples_per_s``."""
+the window's warm HieAvg mixes (``work.aggregate_work`` over the model's
+parameters, bytes-bound) over the device time of the whole aggregation
+phase, the ops under the program's ``bhfl.edge_agg`` and
+``bhfl.global_agg`` scopes, which ``round.aggregate_s`` reads too.
 
-FRAMES = [("kernels/hieavg_agg.py", "*"),
-          ("kernels/coef_agg.py", "*"),
-          ("core/hieavg.py", "*"),
-          ("kernels/dispatch.py", "edge_aggregate_batched"),
-          ("kernels/dispatch.py", "global_aggregate"),
-          ("kernels/dispatch.py", "edge_aggregate_cold_batched"),
-          ("kernels/dispatch.py", "global_aggregate_cold"),
-          ("kernels/ops.py", "fused_mix_and_update"),
-          ("kernels/ops.py", "fused_edge_aggregate_batched"),
-          ("kernels/ops.py", "fused_coef_aggregate")]
-OP_NAMES = ["hieavg_agg", "coef_agg"]
+That phase also initialises the histories and broadcasts each edge's
+model back to its device slots.  The least count leaves both out on
+purpose, so the share says how far the whole phase is from the traffic
+that the mix itself needs.  ``None`` where the program names no phases.
+Moves ``samples_per_s``."""
+import phases
+import work
 
 
 def read(run):
-    t = run.trace.attributed_s(FRAMES, OP_NAMES)
-    if t <= 0:
+    tel = phases.telemetry()
+    t = tel and phases.per_round_s(run, (tel.EDGE_AGG, tel.GLOBAL_AGG))
+    if not t:
         return None
-    least, _ = run.least_time(run.work["agg_flops"], run.work["agg_bytes"])
-    return 100.0 * least / t
+    n = work.n_params(run.model.param_shapes(run.setting))
+    least, _ = run.least_time(*work.aggregate_work(
+        n, run.agg_participants, run.agg_outputs))
+    return 100.0 * least / (t * run.rounds)

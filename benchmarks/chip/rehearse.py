@@ -8,9 +8,11 @@ deployment from seed 0 on the host, lowers the one-round
 ``run_engine_chunk`` program with the compiled Pallas kernels for one chip
 of a described ``v5e:2x2``, compiles it, and prints one JSON line: the
 compiler's ``memory_analysis()`` (argument, output and temporary bytes),
-the number of Pallas custom calls, and the compile seconds.  The TPU
-compiler refuses here what it would refuse on the chip: a kernel that
-does not tile, a program that does not fit.  ``--hlo-dir`` also writes
+the number of Pallas custom calls, the compile seconds, and the
+parameters of the client model that the configuration names, whose
+module's ``param_shapes`` must match the program's layout leaf for leaf.
+The TPU compiler refuses here what it would refuse on the chip: a kernel
+that does not tile, a program that does not fit.  ``--hlo-dir`` also writes
 each compiled program's text, whose instruction metadata the trace
 reader attributes device time by.
 """
@@ -27,6 +29,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import cell as cells  # noqa: E402
+import run  # noqa: E402
+import work  # noqa: E402
 
 
 def rehearse(workload: str, hlo_dir: str | None) -> dict:
@@ -39,6 +43,12 @@ def rehearse(workload: str, hlo_dir: str | None) -> dict:
     sim = cells.build_simulator(c.config, c.traffic, seed=0,
                                 kernel_mode="pallas")
     inp = engine.build_inputs(sim)
+    _, model = run.load_models(c)
+    leaves = model.param_shapes(c.config["setting"])
+    layout = {k: tuple(v.shape[1:]) for k, v in inp.init_w.items()}
+    if layout != leaves:
+        raise ValueError(f"{c.config['model']}.param_shapes {leaves} does "
+                         f"not match the program's layout {layout}")
     carry = engine.init_engine_carry(inp, sim.history_dtype)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -60,7 +70,8 @@ def rehearse(workload: str, hlo_dir: str | None) -> dict:
             "output_bytes": mem.output_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
             "generated_code_bytes": mem.generated_code_size_in_bytes,
-            "pallas_custom_calls": text.count("tpu_custom_call")}
+            "pallas_custom_calls": text.count("tpu_custom_call"),
+            "model_params": work.n_params(leaves)}
 
 
 def main(argv=None) -> int:

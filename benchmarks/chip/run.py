@@ -8,7 +8,9 @@ none it exits 1 and prints no result.  One run:
 
 1. builds the cell's deployment from ``--seed`` (``BHFLSetting.seed``: data,
    partition, batches, latency, chain, faults and cohorts all come from its
-   named streams) and the initial weights, on the device, from the seed;
+   named streams) and the initial weights, on the device, from the seed,
+   by the client model's module (``models/<model>.py``, which the
+   configuration names: the one file that knows the model);
 2. compiles the one-round ``engine.run_engine_chunk`` program ahead of
    time (``kernel_mode="auto"``: the compiled Pallas kernels on a TPU),
    from JAX's persistent cache after a checkout's first run;
@@ -19,7 +21,8 @@ none it exits 1 and prints no result.  One run:
    round); after round T it restarts from the carry saved after cold
    boot, so every round in the window is a warm HieAvg round;
 5. with ``--trace 1`` traces that window instead and reduces the trace
-   to the per-layer metrics (``metrics/<name>.py``) and a breakdown;
+   to the per-layer metrics (``metrics/<name>.py``, each reading the one
+   context ``metric_context`` builds) and a breakdown;
 6. frees the program's state and runs the plain reference over the same
    first rounds, and compares (``compare.py``, ``limits/<workload>.json``).
 
@@ -83,16 +86,23 @@ def peak_for(kind: str) -> dict:
     return peaks[kind]
 
 
-def prepare(c: cells.Cell, seed: int, ref) -> types.SimpleNamespace:
-    """Set-up up to the compile: the deployment, the initial weights made
-    from the seed, the input planes cut per round, the round-zero carry,
-    and the planes the reference reads."""
+def load_models(c: cells.Cell):
+    """The plain reference and the client model module that the cell's
+    configuration names."""
+    return (load_module(HERE / "references" / f"{c.config['reference']}.py"),
+            load_module(HERE / "models" / f"{c.config['model']}.py"))
+
+
+def prepare(c: cells.Cell, seed: int, model) -> types.SimpleNamespace:
+    """Set-up up to the compile: the deployment, the initial weights that
+    ``model`` makes from the seed, the input planes cut per round, the
+    round-zero carry, and the planes the reference reads."""
     import jax
     import numpy as np
     from repro.fl import engine
 
     sim = cells.build_simulator(c.config, c.traffic, seed)
-    w0 = ref.init_params(c.config, seed)
+    w0 = model.init_params(c.config, seed)
     inp = cells.with_init_weights(engine.build_inputs(sim), w0)
     T, t_c = int(inp.t_valid), int(inp.t_cold_boot)
     checked = t_c + 1                   # cold-boot rounds + first HieAvg one
@@ -127,7 +137,8 @@ def compile_round(p: types.SimpleNamespace):
     ).compile()
 
 
-def first_rounds(compiled, p: types.SimpleNamespace) -> tuple[dict, tuple]:
+def first_rounds(compiled, p: types.SimpleNamespace, model
+                 ) -> tuple[dict, tuple]:
     """Drive ``compiled`` from round zero through the checked rounds.
     Returns the outputs the check compares, and the carry after cold boot
     (the window's restart point) and after the checked rounds."""
@@ -135,13 +146,13 @@ def first_rounds(compiled, p: types.SimpleNamespace) -> tuple[dict, tuple]:
 
     prog = {"loss": [], "correct": [], "clock": [], "energy": [],
             "models": []}
-    n_test = int(p.planes["test_y"].shape[0])
+    n_eval = model.n_eval(p.planes)
     carry = restart = p.carry
     for i in range(p.checked):
         outs, carry = compiled(p.rounds[i], carry, p.starts[i])
         acc, loss, _, clock, energy = (float(np.asarray(o)[0]) for o in outs)
         prog["loss"].append(loss)
-        prog["correct"].append(round(acc * n_test))
+        prog["correct"].append(round(acc * n_eval))
         prog["clock"].append(clock)
         prog["energy"].append(energy)
         prog["models"].append({k: np.asarray(v) for k, v in
@@ -149,6 +160,31 @@ def first_rounds(compiled, p: types.SimpleNamespace) -> tuple[dict, tuple]:
         if i == p.t_c - 1:
             restart = carry
     return prog, (restart, carry)
+
+
+def metric_context(c: cells.Cell, model, p: types.SimpleNamespace,
+                   rounds: int, trace, peak: dict, setup: dict
+                   ) -> types.SimpleNamespace:
+    """What every per-layer metric reads (``metrics/<name>.py``'s
+    ``read``): the cell's ``config``, its ``setting``, the client
+    ``model`` module, ``chips``, the device's ``peak``, the window's
+    ``trace``, the harness's ``setup`` seconds, ``least_time(flops,
+    bytes)``, and the counts of the window's ``rounds``: ``train_samples``
+    (real ones, as ``samples_per_s`` counts them), ``eval_samples``, and
+    the models that its aggregations mix (``agg_participants``: each edge
+    round's device slots and each global round's edges) and write
+    (``agg_outputs``: each edge's model and the global one).  A metric
+    counts its own work from these; nothing here is a model's size."""
+    s = c.config["setting"]
+    k, n_edges = s["k_edge_rounds"], len(p.planes["j_arr"])
+    return types.SimpleNamespace(
+        config=c.config, setting=s, model=model, chips=c.chips, peak=peak,
+        trace=trace, setup=setup,
+        least_time=lambda f, b: work.least_time(f, b, peak),
+        rounds=rounds, train_samples=rounds * p.samples,
+        eval_samples=rounds * model.n_eval(p.planes),
+        agg_participants=rounds * (k * p.slots + n_edges),
+        agg_outputs=rounds * (k * n_edges + 1))
 
 
 def run_cell(c: cells.Cell, seed: int, seconds: float, trace: bool, *,
@@ -160,23 +196,22 @@ def run_cell(c: cells.Cell, seed: int, seconds: float, trace: bool, *,
 
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    ref = load_module(HERE / "references" / f"{c.config['reference']}.py")
+    ref, model = load_models(c)
     devs = jax.devices()[:c.chips]
     now = time.perf_counter
     setup = {}
 
     with span("setup.inputs"):
         t = now()
-        p = prepare(c, seed, ref)
+        p = prepare(c, seed, model)
         setup["inputs_s"] = now() - t
     with span("setup.compile"):
         t = now()
         compiled = compile_round(p)
         setup["compile_s"] = now() - t
     with span("setup.first_rounds"):
-        prog, (restart, carry) = first_rounds(compiled, p)
+        prog, (restart, carry) = first_rounds(compiled, p, model)
     planes, w0 = p.planes, p.w0
-    n_test = int(planes["test_y"].shape[0])
 
     # ---- the window
     tdir = None
@@ -230,20 +265,8 @@ def run_cell(c: cells.Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         from devtrace import Trace
         tr = Trace.read(tdir, hlo_text)
-        peak = peak_for(dev.device_kind)
-        s = c.config["setting"]
-        samples = n_rounds * p.samples
-        conv_f, conv_b = work.conv_work(s, samples, n_rounds * n_test)
-        k, n_edges = s["k_edge_rounds"], len(planes["j_arr"])
-        agg_f, agg_b = work.aggregate_work(
-            s, n_rounds * (k * p.slots + n_edges),
-            n_rounds * (k * n_edges + 1))
-        ctx = types.SimpleNamespace(
-            trace=tr, chips=c.chips, peak=peak, setup=setup,
-            least_time=lambda f, b: work.least_time(f, b, peak),
-            work={"train_flops": samples * work.train_flops_per_sample(s),
-                  "conv_flops": conv_f, "conv_bytes": conv_b,
-                  "agg_flops": agg_f, "agg_bytes": agg_b})
+        ctx = metric_context(c, model, p, n_rounds, tr,
+                             peak_for(dev.device_kind), setup)
         for m in c.per_layer:
             v = load_module(HERE / "metrics" / f"{m['name']}.py").read(ctx)
             if v is not None:
@@ -266,7 +289,7 @@ def run_cell(c: cells.Cell, seed: int, seconds: float, trace: bool, *,
     checked = p.checked
     del compiled, carry, restart, outs, p
     gc.collect()
-    got = ref.run(c.config, planes, w0, checked)
+    got = ref.run(model, c.config, planes, w0, checked)
     ok, checks = compare.judge(compare.numbers(prog, got, w0), c.limits)
     result["correct"] = ok and failed == 0
     result["checks"] = checks

@@ -21,7 +21,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import run  # noqa: E402
-from tiny import tiny_cell  # noqa: E402
+from tiny import RECORDED_SEED, tiny_cell  # noqa: E402
 
 
 def scrub(data: bytes) -> bytes:
@@ -31,8 +31,8 @@ def scrub(data: bytes) -> bytes:
 
 def main(out: Path) -> dict:
     raw = out / "raw"
-    res = run.run_cell(tiny_cell(), seed=5, seconds=0.0, trace=True,
-                       trace_dir=str(raw))
+    res = run.run_cell(tiny_cell(), seed=RECORDED_SEED, seconds=0.0,
+                       trace=True, trace_dir=str(raw))
     pb = glob.glob(str(raw / "**" / "*.xplane.pb"), recursive=True)[0]
     for src, dst in ((pb, "window.xplane.pb.gz"),
                      (raw / "window.hlo.txt", "window.hlo.txt.gz")):

@@ -39,10 +39,10 @@ def test_sound_run_is_correct(cell):
 
 
 def test_control_is_not_correct(cell):
-    ref = run.load_module(run.HERE / "references" / "bhfl_cnn.py")
-    p = run.prepare(cell, 22, ref)
-    base = ref.run(cell.config, p.planes, p.w0, p.checked)
-    ctl = ref.run(cell.config, p.planes, p.w0, p.checked,
+    ref, model = run.load_models(cell)
+    p = run.prepare(cell, 22, model)
+    base = ref.run(model, cell.config, p.planes, p.w0, p.checked)
+    ctl = ref.run(model, cell.config, p.planes, p.w0, p.checked,
                   dtype=jnp.bfloat16)
     ok, checks = compare.judge(compare.numbers(ctl, base, p.w0),
                                cell.limits)

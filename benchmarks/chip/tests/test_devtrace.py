@@ -2,46 +2,38 @@
 
 ``data/small_trace`` holds the window of the tiny cell (``tiny.py``), as
 ``record_trace.py`` recorded it: the profiler's ``.xplane.pb`` and the
-window program's text, both gzipped.
+window program's text, both gzipped, and the run's result line.  The
+readers get the context that ``run.metric_context`` builds for that run.
 """
-import gzip
-import importlib.util
 import json
-import shutil
 
 import pytest
 
-import work
+import run as harness
 from cell import HERE
-from devtrace import CONTROL, SPAN, WINDOW, Trace
-from tiny import tiny_cell
-
-DATA = HERE / "tests" / "data" / "small_trace"
+from devtrace import CONTROL, SPAN, WINDOW
+from tiny import DATA, RECORDED_SEED, read_trace, reader, tiny_cell
 
 
 @pytest.fixture(scope="module")
 def trace(tmp_path_factory):
-    d = tmp_path_factory.mktemp("trace")
-    prof = d / "plugins" / "profile" / "recorded"
-    prof.mkdir(parents=True)
-    with gzip.open(DATA / "window.xplane.pb.gz") as src, \
-            open(prof / "window.xplane.pb", "wb") as dst:
-        shutil.copyfileobj(src, dst)
-    hlo = gzip.open(DATA / "window.hlo.txt.gz", "rt").read()
-    return Trace.read(str(d), hlo)
+    return read_trace("small_trace", tmp_path_factory.mktemp("trace"))
 
 
 @pytest.fixture(scope="module")
 def recorded():
-    return json.loads((DATA / "result.json").read_text())
+    return json.loads((DATA / "small_trace" / "result.json").read_text())
 
 
-def reader(name):
-    spec = importlib.util.spec_from_file_location(
-        name.replace(".", "_"), HERE / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+@pytest.fixture(scope="module")
+def context(trace, recorded):
+    """The metric context of the recorded run, rebuilt from its seed."""
+    c = tiny_cell()
+    _, model = harness.load_models(c)
+    p = harness.prepare(c, RECORDED_SEED, model)
+    peak = json.loads((HERE / "peaks.json").read_text())["TPU v5 lite"]
+    return harness.metric_context(c, model, p, recorded["attempted"], trace,
+                                  peak, {})
 
 
 def test_window_and_busy_time(trace, recorded):
@@ -64,10 +56,9 @@ def test_ops_are_named_by_instruction_and_attributed(trace):
 
 
 def test_attributed_work_is_inside_the_busy_time(trace):
-    conv = reader("conv_roofline")
-    agg = reader("aggregate_roofline")
-    t_conv = trace.attributed_s(conv.FRAMES, conv.OP_NAMES)
-    t_agg = trace.attributed_s(agg.FRAMES, agg.OP_NAMES)
+    from repro import telemetry as tel
+    t_conv = trace.attributed_s(reader("conv_roofline").FRAMES)
+    t_agg = trace.attributed_s(op_names=(tel.EDGE_AGG, tel.GLOBAL_AGG))
     assert t_conv > 0 and t_agg > 0
     assert t_conv + t_agg <= trace.busy_s()
 
@@ -81,30 +72,30 @@ def test_idle_gaps_are_named_by_host_spans(trace):
     assert any(k != WINDOW for k, _ in gaps)
 
 
-def test_rooflines_and_mfu_are_shares(trace, recorded):
-    c = tiny_cell()
-    s = c.config["setting"]
+def test_context_counts_the_windows_work(context, recorded):
+    # 2 edges x 3 devices, 2 SGD steps of batch 8, K=2; 100 test images
     rounds = recorded["attempted"]
-    samples = s["n_edges"] * s["j_per_edge"] * 2 * s["batch_size"] \
-        * s["k_edge_rounds"]
-    peak = json.loads((HERE / "peaks.json").read_text())["TPU v5 lite"]
-    conv_f, conv_b = work.conv_work(s, rounds * samples, rounds * 100)
-    agg_f, agg_b = work.aggregate_work(
-        s, rounds * (s["k_edge_rounds"] * 6 + 2),
-        rounds * (s["k_edge_rounds"] * 2 + 1))
+    assert (context.rounds, context.chips) == (rounds, 1)
+    assert context.train_samples == rounds * 6 * 2 * 8 * 2
+    assert context.eval_samples == rounds * 100
+    assert context.agg_participants == rounds * (2 * 6 + 2)
+    assert context.agg_outputs == rounds * (2 * 2 + 1)
 
-    class Run:
-        pass
 
-    run = Run()
-    run.trace, run.chips, run.peak, run.setup = trace, 1, peak, {}
-    run.least_time = lambda f, b: work.least_time(f, b, peak)
-    run.work = {"train_flops": rounds * samples
-                * work.train_flops_per_sample(s),
-                "conv_flops": conv_f, "conv_bytes": conv_b,
-                "agg_flops": agg_f, "agg_bytes": agg_b}
+def test_rooflines_and_mfu_are_shares(context, recorded):
     for name in ("train_mfu", "conv_roofline", "aggregate_roofline",
                  "device.idle_share"):
-        v = reader(name).read(run)
+        v = reader(name).read(context)
         assert v is not None and 0 < v < 100, name
         assert v == pytest.approx(recorded["metrics"][name]["value"]), name
+
+
+def test_aggregate_roofline_is_the_least_time_over_the_phase(context):
+    # HieAvg's least traffic over the 144,266 parameters of the paper's
+    # CNN, bytes-bound, against the whole aggregation phase's device time
+    least = 4 * 144266 * (5 * context.agg_participants
+                          + context.agg_outputs) / 819e9
+    share = reader("aggregate_roofline").read(context)
+    phase = reader("round.aggregate_s").read(context)
+    assert share / 100 * phase * context.rounds == pytest.approx(
+        least, rel=1e-9)

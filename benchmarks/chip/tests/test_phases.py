@@ -2,13 +2,10 @@
 trace, set-up from the program's host spans and compile counters.
 
 The phase readers are checked on a window program written out here, and
-on ``data/small_trace``, recorded on a TPU v5e from a program that named no
-round phases, where they read nothing.  The set-up readers read this
-process's own spans and counters.
+on ``data/unscoped_trace``, recorded on a TPU v5e from an earlier version
+of the program that named no round phases, where they read nothing.  The
+set-up readers read this process's own spans and counters.
 """
-import gzip
-import importlib.util
-import shutil
 import types
 
 import jax
@@ -16,36 +13,20 @@ import jax.numpy as jnp
 import pytest
 
 import cell as cells
-from cell import HERE
 from devtrace import Trace
-from tiny import tiny_cell
+from tiny import read_trace, reader, tiny_cell
 
-UNSCOPED = HERE / "tests" / "data" / "small_trace"
 ROUND = ("round.train_s", "round.aggregate_s", "round.eval_s",
          "round.unscoped_s")
 SETUP = ("setup.sim_s", "setup.replay_s", "setup.planes_s",
          "compile.trace_s")
 
 
-def reader(name):
-    spec = importlib.util.spec_from_file_location(
-        name.replace(".", "_"), HERE / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def unscoped(tmp_path_factory):
     """The recorded trace, as ``run.run_cell`` hands it to a reader."""
-    d = tmp_path_factory.mktemp("small_trace")
-    prof = d / "plugins" / "profile" / "recorded"
-    prof.mkdir(parents=True)
-    with gzip.open(UNSCOPED / "window.xplane.pb.gz") as src, \
-            open(prof / "window.xplane.pb", "wb") as dst:
-        shutil.copyfileobj(src, dst)
-    hlo = gzip.open(UNSCOPED / "window.hlo.txt.gz", "rt").read()
-    return types.SimpleNamespace(trace=Trace.read(str(d), hlo))
+    return types.SimpleNamespace(trace=read_trace(
+        "unscoped_trace", tmp_path_factory.mktemp("unscoped_trace")))
 
 
 #: A window program whose ops name the round phases, as the engine's do.
