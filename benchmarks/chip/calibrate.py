@@ -11,7 +11,8 @@ in the program's place, at the cell's own size:
 * ``program``: the window's compiled one-round program, as a run's set-up
   drives it (the lower readings come from sound runs of this);
 * ``control``: the reference computed in bfloat16, the nearest precision
-  below the configuration's float32;
+  below the configuration's float32 (frozen weights too; integer planes,
+  such as token ids, keep their dtype);
 * ``half_batch``, ``no_exchange``, ``altered_update``: the reference with
   one of the faults the check must catch planted (see the reference's
   docstring).  A step that returns its state unchanged reads 1 on both
@@ -62,7 +63,7 @@ def main(argv=None) -> int:
     compiled = None
     for seed in args.seeds:
         p = run.prepare(c, seed, model)
-        planes, w0, checked = p.planes, p.w0, p.checked
+        planes, w0, checked, frozen = p.planes, p.w0, p.checked, p.frozen
         prog = None
         if args.program:
             compiled = compiled or run.compile_round(p)
@@ -70,7 +71,7 @@ def main(argv=None) -> int:
         del p
         gc.collect()
         t = time.perf_counter()
-        base = ref.run(model, c.config, planes, w0, checked)
+        base = ref.run(model, c.config, planes, w0, checked, frozen=frozen)
         secs = {"reference": time.perf_counter() - t}
         stand_ins = {"program": prog} if prog else {}
         for v in args.variants:
@@ -78,7 +79,7 @@ def main(argv=None) -> int:
             stand_ins[v] = ref.run(
                 model, c.config, planes, w0, checked,
                 dtype=jnp.bfloat16 if v == "control" else jnp.float32,
-                fault=None if v == "control" else v)
+                fault=None if v == "control" else v, frozen=frozen)
             secs[v] = time.perf_counter() - t
         for name, got in stand_ins.items():
             print(json.dumps({"workload": c.name, "seed": seed,

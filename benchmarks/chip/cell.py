@@ -22,7 +22,15 @@ contract (written out in ``models/cnn.py``):
     param_shapes(setting), init_params(config, seed), loss(p, x, y),
     test_count(p, x, y), n_eval(planes), train_flops_per_sample(setting)
 
-and may add counts of its kernels' work, such as ``conv_work``.
+and may add counts of its kernels' work, such as ``conv_work``.  A model
+that trains over weights it never changes adds ``frozen_params(config)``
+and takes them as a fourth argument, ``loss(p, x, y, frozen)`` and
+``test_count(p, x, y, frozen)``; its ``param_shapes`` are then the
+federated parameters alone, and ``train_flops_per_sample`` counts the
+forward pass, the input gradients through the frozen layers and the
+weight gradients of the federated parameters only.  A model may also say
+how many device slots its plain reference trains at once,
+``reference_block(setting)`` (all of them where it does not).
 """
 from __future__ import annotations
 
@@ -112,6 +120,25 @@ def with_init_weights(inp, weights: dict):
                          f"program's layout {want}")
     return dataclasses.replace(
         inp, init_w=jax.tree.map(lambda v: v[None], weights))
+
+
+def with_frozen_weights(inp, frozen: dict):
+    """``inp`` with the model's frozen weights (made by the benchmark from
+    the configuration) as its ``frozen_w`` field, the one copy that every
+    device slot reads, checked leaf for leaf against the program's own
+    layout of that field."""
+    import jax
+
+    if "frozen_w" not in {f.name for f in dataclasses.fields(inp)}:
+        raise ValueError(
+            f"the model has frozen weights, and the program's inputs "
+            f"({type(inp).__name__}) have no frozen_w field to take them")
+    want = jax.tree.map(lambda v: (v.shape, v.dtype), inp.frozen_w)
+    got = jax.tree.map(lambda v: (v.shape, v.dtype), frozen)
+    if want != got:
+        raise ValueError(f"frozen weights {got} do not match the program's "
+                         f"frozen_w layout {want}")
+    return dataclasses.replace(inp, frozen_w=frozen)
 
 
 def chunk_kwargs(sim) -> dict:

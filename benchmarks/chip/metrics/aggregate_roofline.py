@@ -4,8 +4,10 @@ parameters, bytes-bound) over the device time of the whole aggregation
 phase, the ops under the program's ``bhfl.edge_agg`` and
 ``bhfl.global_agg`` scopes, which ``round.aggregate_s`` reads too.
 
-That phase also initialises the histories and broadcasts each edge's
-model back to its device slots.  The least count leaves both out on
+The count is of the model's ``param_shapes``, its federated parameters;
+a model's frozen weights are never mixed and are not counted.  That
+phase also initialises the histories and broadcasts each edge's model
+back to its device slots.  The least count leaves both out on
 purpose, so the share says how far the whole phase is from the traffic
 that the mix itself needs.  ``None`` where the program names no phases.
 Moves ``samples_per_s``."""

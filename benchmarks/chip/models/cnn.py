@@ -23,6 +23,29 @@ a size of the model.  Every model module keeps this contract:
 Counts of a kernel's own work, such as ``conv_work`` here, are optional;
 a metric that needs one reads nothing where the model has none.  A
 multiply-add is two FLOPs.
+
+Optional parts, which this CNN does not have:
+
+    frozen_params(config)   weights that every device slot shares and no
+                            step changes (a pretrained backbone), a flat
+                            dict made on the device in one jitted call
+                            from a seed fixed in the configuration, not
+                            the run's.  A model with them takes them as a
+                            fourth argument, ``loss(p, x, y, frozen)`` and
+                            ``test_count(p, x, y, frozen)``; its
+                            ``param_shapes`` are then the federated
+                            parameters alone (what is trained, exchanged
+                            and aggregated, and all that
+                            ``aggregate_roofline`` and the update gaps of
+                            ``correct`` read), and its
+                            ``train_flops_per_sample`` counts the forward
+                            pass, the input gradients through the frozen
+                            layers, and the weight gradients of the
+                            federated parameters only.
+    reference_block(setting)
+                            device slots that the plain reference trains
+                            at once (default: all of them), for a model
+                            whose activations for every slot would not fit
 """
 from __future__ import annotations
 

@@ -10,13 +10,19 @@ consensus stall (C2), and the consensus energy accumulates.
 
 The client model is a module of ``models/`` that the configuration names
 (its contract is in ``models/cnn.py``); this module reads only its
-``loss`` and ``test_count``, and no size of it.
+``loss``, ``test_count`` and, where it has them, ``reference_block`` and
+frozen weights, and no size of it.  Frozen weights are the same for
+every device slot: they enter each jitted function as an argument, are
+never broadcast to slots or aggregated, and no gradient is taken of
+them.  ``reference_block`` device slots train at once (all of them where
+the module does not say), one block after another.
 
 It imports nothing of the program.  It reads the deployment's input
 planes (data, batch indices, submission masks, per-device time draws and
 per-round consensus draws, as the seed made them) and the benchmark's own
 initial weights, and computes everything else itself, in the dtype it is
 given: float32 is the configuration's precision, bfloat16 the control.
+Integer planes, such as token ids, keep their own dtype.
 
 ``fault`` plants one of the faults the check must catch, for reading
 their limits on the chip: ``"half_batch"`` (each step's loss is the mean
@@ -35,18 +41,48 @@ import numpy as np
 FAULTS = (None, "half_batch", "no_exchange", "altered_update")
 
 
+def _with_frozen(frozen):
+    """The trailing arguments of a model's ``loss`` and ``test_count``."""
+    return () if frozen is None else (frozen,)
+
+
 # ------------------------------------------------------------ one device
-def local_epoch(model, p, xs, ys, lr, half):
+def local_epoch(model, p, xs, ys, lr, half, frozen=None):
     """SGD on ``model.loss`` over ``xs`` [steps, B, ...]; returns
-    (params, mean step loss)."""
+    (params, mean step loss).  The gradient is taken of ``p`` alone."""
     def step(p, xy):
         x, y = xy
         if half:
             x, y = x[:x.shape[0] // 2], y[:y.shape[0] // 2]
-        v, g = jax.value_and_grad(model.loss)(p, x, y)
+        v, g = jax.value_and_grad(model.loss)(p, x, y, *_with_frozen(frozen))
         return jax.tree.map(lambda w, gw: w - lr * gw, p, g), v
     p, vs = jax.lax.scan(step, p, (xs, ys))
     return p, jnp.mean(vs)
+
+
+def train_slots(one, dev_w, x, y, lr, block):
+    """``one`` local epoch on every device slot of ``dev_w`` [N, J, ...],
+    ``block`` slots at a time.  A block of all N x J slots is one vmapped
+    computation; smaller blocks run one after another over the flattened
+    slots (the last one shorter where ``block`` does not divide them), so
+    only one block's activations are live at once."""
+    N, J = x.shape[:2]
+    if block >= N * J:
+        train = jax.vmap(jax.vmap(one, in_axes=(0, 0, 0, None)),
+                         in_axes=(0, 0, 0, None))
+        return train(dev_w, x, y, lr)
+    slots = jax.tree.map(lambda a: a.reshape((N * J,) + a.shape[2:]),
+                         (dev_w, x, y))
+    train = jax.vmap(one, in_axes=(0, 0, 0, None))
+    split = N * J // block * block
+    blocks = jax.tree.map(
+        lambda v: v[:split].reshape((-1, block) + v.shape[1:]), slots)
+    full = jax.lax.map(lambda a: train(*a, lr), blocks)
+    parts = [jax.tree.map(lambda v: v.reshape((split,) + v.shape[2:]), full)]
+    if split < N * J:
+        parts.append(train(*jax.tree.map(lambda v: v[split:], slots), lr))
+    out = jax.tree.map(lambda *vs: jnp.concatenate(vs), *parts)
+    return jax.tree.map(lambda v: v.reshape((N, J) + v.shape[1:]), out)
 
 
 # ------------------------------------------------------------ HieAvg layer
@@ -86,20 +122,23 @@ def new_history(ws):
 
 
 # ------------------------------------------------------------- the rounds
-@partial(jax.jit, static_argnames=("model", "warm", "first", "fault"))
+@partial(jax.jit, static_argnames=("model", "warm", "first", "fault",
+                                   "block"))
 def edge_round(dev_w, ehist, train_x, train_y, bidx, has, valid, dmask, lr,
-               gamma0, lam, *, model, warm, first, fault):
-    """K-th edge round of all edges: local epochs, then each edge's
-    aggregation; returns (device models synced to their edge model,
-    edge history, device losses [N, J])."""
+               gamma0, lam, frozen, *, model, warm, first, fault, block):
+    """K-th edge round of all edges: local epochs, ``block`` device slots
+    at a time, then each edge's aggregation; returns (device models synced
+    to their edge model, edge history, device losses [N, J])."""
     N, J = valid.shape
     x, y = train_x[bidx], train_y[bidx]          # [N, J, steps, B, ...]
-    x = x * has.reshape(has.shape + (1,) * (x.ndim - 2))
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        x = x * has.reshape(has.shape + (1,) * (x.ndim - 2))
+    else:
+        x = jnp.where(has.reshape(has.shape + (1,) * (x.ndim - 2)) > 0, x, 0)
     y = jnp.where(has.reshape(has.shape + (1,) * (y.ndim - 2)) > 0, y, 0)
-    one = partial(local_epoch, model, half=fault == "half_batch")
-    train = jax.vmap(jax.vmap(one, in_axes=(0, 0, 0, None)),
-                     in_axes=(0, 0, 0, None))
-    ws, dev_loss = train(dev_w, x, y, lr)
+    one = partial(local_epoch, model, half=fault == "half_batch",
+                  frozen=frozen)
+    ws, dev_loss = train_slots(one, dev_w, x, y, lr, block)
     if fault == "altered_update":
         ws = jax.tree.map(lambda a, b: a.at[0, 0].add(a[0, 0] - b[0, 0]),
                           ws, dev_w)
@@ -142,22 +181,33 @@ def round_time(dev_time_t, valid, emask, j_arr, cons_t, edge_hop):
 
 
 def run(model, config: dict, planes: dict, init_w: dict, rounds: int,
-        dtype=jnp.float32, fault=None) -> dict:
-    """The first ``rounds`` global rounds of ``model``.  Returns per-round
-    ``loss``, ``correct`` (eval targets right), ``clock`` and ``energy``,
-    and the global model after each round (``models``)."""
+        dtype=jnp.float32, fault=None, frozen: dict | None = None) -> dict:
+    """The first ``rounds`` global rounds of ``model``, over its
+    ``frozen`` weights where it has them.  Returns per-round ``loss``,
+    ``correct`` (eval targets right), ``clock`` and ``energy``, and the
+    global model after each round (``models``)."""
     if fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}")
     s = config["setting"]
     K, t_cold = s["k_edge_rounds"], s["t_cold_boot"]
     f = lambda a: jnp.asarray(a, dtype)                      # noqa: E731
-    train_x, test_x = f(planes["train_x"]), f(planes["test_x"])
+
+    def fp(a):
+        """``a`` in the run's dtype where it is floating."""
+        return f(a) if jnp.issubdtype(a.dtype, jnp.floating) \
+            else jnp.asarray(a)
+
+    train_x, test_x = fp(planes["train_x"]), fp(planes["test_x"])
+    frozen = None if frozen is None else {k: fp(v) for k, v in frozen.items()}
     train_y, test_y = jnp.asarray(planes["train_y"]), jnp.asarray(
         planes["test_y"])
     has, valid = f(planes["has_data"]), jnp.asarray(planes["valid"])
     j_arr = f(planes["j_arr"])
     gamma0, lam = f(s["gamma0"]), f(s["lam"])
     N, J = valid.shape
+    block = getattr(model, "reference_block", lambda _: N * J)(s)
+    if block < 1:
+        raise ValueError(f"reference_block {block} trains no device slot")
     g = {k: f(v) for k, v in init_w.items()}
     dev_w = {k: jnp.broadcast_to(v, (N, J) + v.shape)
              for k, v in g.items()}
@@ -172,7 +222,8 @@ def run(model, config: dict, planes: dict, init_w: dict, rounds: int,
             dev_w, ehist, dev_loss = edge_round(
                 dev_w, ehist, train_x, train_y, planes["batch_idx"][t, k],
                 has, valid, planes["dev_masks"][t, k], lr, gamma0, lam,
-                model=model, warm=warm, first=r == 0, fault=fault)
+                frozen, model=model, warm=warm, first=r == 0, fault=fault,
+                block=block)
         edge_w = {k: v[:, 0] for k, v in dev_w.items()}
         g, ghist = global_round(edge_w, ghist, planes["edge_masks"][t], j_arr,
                                 gamma0, lam, warm=warm, first=t == 0)
@@ -181,7 +232,8 @@ def run(model, config: dict, planes: dict, init_w: dict, rounds: int,
         vf = valid.astype(dtype)
         out["loss"].append(jnp.sum(dev_loss * vf)
                            / jnp.maximum(jnp.sum(vf), 1))
-        out["correct"].append(model.test_count(g, test_x, test_y))
+        out["correct"].append(model.test_count(g, test_x, test_y,
+                                               *_with_frozen(frozen)))
         clock = clock + round_time(f(planes["dev_time"][t]), valid,
                                    jnp.asarray(planes["edge_masks"][t]), j_arr,
                                    f(planes["cons_time"][t]),

@@ -11,6 +11,9 @@ compiler's ``memory_analysis()`` (argument, output and temporary bytes),
 the number of Pallas custom calls, the compile seconds, and the
 parameters of the client model that the configuration names, whose
 module's ``param_shapes`` must match the program's layout leaf for leaf.
+A model's frozen weights, where it has them, go in as the program's
+``frozen_w`` input (their shapes only, checked as a run checks them), so
+the compile counts them among the arguments.
 The TPU compiler refuses here what it would refuse on the chip: a kernel
 that does not tile, a program that does not fit.  ``--hlo-dir`` also writes
 each compiled program's text, whose instruction metadata the trace
@@ -44,6 +47,9 @@ def rehearse(workload: str, hlo_dir: str | None) -> dict:
                                 kernel_mode="pallas")
     inp = engine.build_inputs(sim)
     _, model = run.load_models(c)
+    if hasattr(model, "frozen_params"):
+        inp = cells.with_frozen_weights(
+            inp, jax.eval_shape(lambda: model.frozen_params(c.config)))
     leaves = model.param_shapes(c.config["setting"])
     layout = {k: tuple(v.shape[1:]) for k, v in inp.init_w.items()}
     if layout != leaves:

@@ -10,7 +10,9 @@ none it exits 1 and prints no result.  One run:
    partition, batches, latency, chain, faults and cohorts all come from its
    named streams) and the initial weights, on the device, from the seed,
    by the client model's module (``models/<model>.py``, which the
-   configuration names: the one file that knows the model);
+   configuration names: the one file that knows the model), and the
+   model's frozen weights where it has them, from the configuration,
+   handed to the program and to the reference alike;
 2. compiles the one-round ``engine.run_engine_chunk`` program ahead of
    time (``kernel_mode="auto"``: the compiled Pallas kernels on a TPU),
    from JAX's persistent cache after a checkout's first run;
@@ -95,15 +97,21 @@ def load_models(c: cells.Cell):
 
 def prepare(c: cells.Cell, seed: int, model) -> types.SimpleNamespace:
     """Set-up up to the compile: the deployment, the initial weights that
-    ``model`` makes from the seed, the input planes cut per round, the
-    round-zero carry, and the planes the reference reads."""
+    ``model`` makes from the seed, its frozen weights where it has them
+    (from the configuration, the same in every run), the input planes cut
+    per round, the round-zero carry, and the planes the reference reads."""
     import jax
     import numpy as np
     from repro.fl import engine
 
     sim = cells.build_simulator(c.config, c.traffic, seed)
     w0 = model.init_params(c.config, seed)
-    inp = cells.with_init_weights(engine.build_inputs(sim), w0)
+    inp = engine.build_inputs(sim)
+    frozen = None
+    if hasattr(model, "frozen_params"):
+        frozen = model.frozen_params(c.config)
+        inp = cells.with_frozen_weights(inp, frozen)
+    inp = cells.with_init_weights(inp, w0)
     T, t_c = int(inp.t_valid), int(inp.t_cold_boot)
     checked = t_c + 1                   # cold-boot rounds + first HieAvg one
     if T <= checked:
@@ -123,7 +131,7 @@ def prepare(c: cells.Cell, seed: int, model) -> types.SimpleNamespace:
     jax.block_until_ready((rounds, starts, carry))
     return types.SimpleNamespace(
         sim=sim, T=T, t_c=t_c, checked=checked, rounds=rounds,
-        starts=starts, carry=carry, planes=planes,
+        starts=starts, carry=carry, planes=planes, frozen=frozen,
         w0={k: np.asarray(v) for k, v in w0.items()},
         samples=cells.samples_per_round(inp),
         slots=int(np.sum(planes["valid"])))
@@ -211,7 +219,7 @@ def run_cell(c: cells.Cell, seed: int, seconds: float, trace: bool, *,
         setup["compile_s"] = now() - t
     with span("setup.first_rounds"):
         prog, (restart, carry) = first_rounds(compiled, p, model)
-    planes, w0 = p.planes, p.w0
+    planes, w0, frozen = p.planes, p.w0, p.frozen
 
     # ---- the window
     tdir = None
@@ -289,7 +297,7 @@ def run_cell(c: cells.Cell, seed: int, seconds: float, trace: bool, *,
     checked = p.checked
     del compiled, carry, restart, outs, p
     gc.collect()
-    got = ref.run(model, c.config, planes, w0, checked)
+    got = ref.run(model, c.config, planes, w0, checked, frozen=frozen)
     ok, checks = compare.judge(compare.numbers(prog, got, w0), c.limits)
     result["correct"] = ok and failed == 0
     result["checks"] = checks

@@ -81,6 +81,7 @@ def test_reference_trains_it_and_correct_separates_the_control(cell,
 
 @pytest.mark.parametrize("path", [
     "run.py", "work.py", "compare.py", "cell.py", "references/bhfl.py",
+    "calibrate.py", "rehearse.py",
     *sorted(p.relative_to(HERE).as_posix()
             for p in (HERE / "metrics").glob("*.py"))])
 def test_harness_names_no_model_size(path):
@@ -88,5 +89,6 @@ def test_harness_names_no_model_size(path):
     no edit of the harness."""
     text = (HERE / path).read_text()
     for key in ("image_hw", "cnn_c1", "cnn_c2", "n_classes", "HIDDEN",
-                "toy_mlp"):
+                "toy_mlp", "toy_lm", "VOCAB", "WIDTH", "RANK", "SEQ",
+                "frozen_seed"):
         assert key not in text, (path, key)
