@@ -179,8 +179,10 @@ def sgd_update(params: PyTree, grads: PyTree, scale, *,
 
 
 def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
-                      mode: str = "auto") -> jnp.ndarray:
-    """The CNN conv block ``relu(conv3x3_same(x, w) + b)``.
+                      mode: str = "auto", relu: bool = True) -> jnp.ndarray:
+    """The CNN conv block ``relu(conv3x3_same(x, w) + b)``; with
+    ``relu=False`` the pre-activation ``conv3x3_same(x, w) + b``, for a
+    block that pools before its ReLU (``models.cnn._features_fused``).
 
     x: [..., H, W, Cin]; w: [3, 3, Cin, Cout]; b: [Cout].  The fused
     modes run XLA's own convolution, which XLA fuses with the bias and
@@ -193,17 +195,22 @@ def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
     ``xla`` (the CPU default) is the engine's original
     ``_conv3x3_same_im2col`` einsum + separate bias/ReLU, bit-identical
     to what ``cnn_apply_fast`` always did.
+
+    Keep the name: ``benchmarks/chip/metrics/conv_roofline.py`` finds the
+    conv's device ops by this function's stack frame, so work moved out
+    of it (or into it) leaves (or joins) the conv's measured time.
     """
     mode = resolve_kernel_mode(mode)
+    act = jax.nn.relu if relu else (lambda v: v)
     if mode == "xla":
         from repro.models.cnn import _conv3x3_same_im2col
-        return jax.nn.relu(_conv3x3_same_im2col(x, w) + b)
+        return act(_conv3x3_same_im2col(x, w) + b)
     f32 = jnp.float32
     y = jax.lax.conv_general_dilated(
         x.reshape((-1,) + x.shape[-3:]), w, (1, 1), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         preferred_element_type=f32)
-    y = jax.nn.relu(y + b.astype(f32)).astype(x.dtype)
+    y = act(y + b.astype(f32)).astype(x.dtype)
     return y.reshape(x.shape[:-1] + y.shape[-1:])
 
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .spec import ParamSpec
 
@@ -102,9 +103,78 @@ def _conv3x3_same_im2col(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 def _pool_flatten(x: jnp.ndarray) -> jnp.ndarray:
     # 2x2 stride-2 max-pool via reshape — identical to reduce_window but its
     # gradient avoids SelectAndScatter, which is pathologically slow on CPU.
+    # The CPU path's pool, and the oracle of the fused path's
+    # ``max_pool2x2`` (tests/test_kernel_plane.py).
     b, h, w_, c = x.shape
     x = x.reshape(b, h // 2, 2, w_ // 2, 2, c).max(axis=(2, 4))
     return x.reshape(x.shape[0], -1)
+
+
+def _windows(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., H, W, C] viewed as [..., H/2, 2, W/2, 2, C]: a bitcast in
+    the layout XLA picks, whose axes -4 and -2 span each 2x2 window."""
+    h, w_, c = x.shape[-3:]
+    return x.reshape(x.shape[:-3] + (h // 2, 2, w_ // 2, 2, c))
+
+
+def max_pool2x2(x: jnp.ndarray) -> jnp.ndarray:
+    """2x2 stride-2 max-pool: [..., H, W, C] -> [..., H/2, W/2, C].
+
+    The same function as ``_pool_flatten``'s pool, gradient included, to
+    the bit: a tie splits the window's gradient evenly, ``dx = (x ==
+    max) · g / count`` with ``count`` the taps equal to the max, the
+    rule of ``reduce_max``'s own derivative (``lax.reduce_window``'s
+    gradient would give a tie's whole gradient to one tap instead).
+
+    Its residual is a 4-bit mask per output, bit ``2i + j`` set where tap
+    ``(i, j)`` equals the max.  The forward finds max and mask in one
+    variadic reduction, one read of ``x``; the backward reads only the
+    pooled gradient and the mask, counts the set bits elementwise, and
+    writes ``dx``.  ``reduce_max``'s own derivative instead reads ``x``
+    twice more at full size: once to count the ties, as a reduction over
+    the window axes, and once to place the gradient.
+
+    A device trace files the backward's ops under this function's frame
+    and the forward's under ``_max_pool_fwd``: JAX gives the ops of a
+    ``custom_vjp`` backward the frame of its call site.
+    """
+    return _max_pool2x2(x)
+
+
+@jax.custom_vjp
+def _max_pool2x2(x):
+    return _windows(x).max(axis=(-4, -2))
+
+
+def _max_pool_fwd(x):
+    v = _windows(x)
+    nd = v.ndim
+    tap = 2 * lax.broadcasted_iota(jnp.uint8, v.shape, nd - 4) \
+        + lax.broadcasted_iota(jnp.uint8, v.shape, nd - 2)
+
+    def pick(a, b):
+        (ma, ka), (mb, kb) = a, b
+        k = jnp.where(ma == mb, ka | kb, jnp.where(ma > mb, ka, kb))
+        return jnp.maximum(ma, mb), k
+
+    return lax.reduce((v, jnp.left_shift(jnp.uint8(1), tap)),
+                      (jnp.array(-jnp.inf, x.dtype), jnp.uint8(0)),
+                      pick, (nd - 4, nd - 2))
+
+
+def _max_pool_bwd(mask, g):
+    bits = [((mask >> k) & 1).astype(g.dtype) for k in range(4)]
+    share = g / ((bits[0] + bits[1]) + (bits[2] + bits[3]))
+    d = [bit * share for bit in bits]
+    # stacked, not broadcast over the window: for the conv backward that
+    # consumes dx, the TPU compiler writes a broadcast out at full size
+    rows = [jnp.stack(d[2 * i:2 * i + 2], axis=-2) for i in range(2)]
+    dx = jnp.stack(rows, axis=-4)       # [..., H/2, 2, W/2, 2, C]
+    h2, w2, c = g.shape[-3:]
+    return (dx.reshape(g.shape[:-3] + (2 * h2, 2 * w2, c)),)
+
+
+_max_pool2x2.defvjp(_max_pool_fwd, _max_pool_bwd)
 
 
 def _apply(params: dict, images: jnp.ndarray, conv) -> jnp.ndarray:
@@ -120,13 +190,21 @@ def _features_fused(params: dict, images: jnp.ndarray, kernel_mode: str
                     ) -> jnp.ndarray:
     """Pooled/flattened features with the conv blocks routed through
     ``kernels.dispatch.conv3x3_bias_relu`` (XLA's own convolution, fused
-    with its bias and ReLU)."""
+    with its bias and ReLU).
+
+    Block 2 pools its pre-activation and applies the ReLU to the pooled
+    tensor: the same values as ``_pool_flatten(relu(...))``, gradients
+    included (a zero may change sign), since the ReLU is monotone and
+    its derivative is 0 at and below 0, with one
+    full-size pass each way (``max_pool2x2``) in place of the ReLU
+    output, its mask and the window reductions."""
     from repro.kernels import dispatch as _kd
-    x = images
-    for w, b in ((params["conv1"], params["b1"]),
-                 (params["conv2"], params["b2"])):
-        x = _kd.conv3x3_bias_relu(x, w, b, mode=kernel_mode)
-    return _pool_flatten(x)
+    x = _kd.conv3x3_bias_relu(images, params["conv1"], params["b1"],
+                              mode=kernel_mode)
+    x = _kd.conv3x3_bias_relu(x, params["conv2"], params["b2"],
+                              mode=kernel_mode, relu=False)
+    x = jax.nn.relu(max_pool2x2(x))
+    return x.reshape(x.shape[:-3] + (-1,))
 
 
 def cnn_apply(params: dict, images: jnp.ndarray) -> jnp.ndarray:

@@ -271,6 +271,120 @@ def test_im2col_col2im_vjp_matches_autodiff(shape):
                                np.asarray(ref_vjp(dcols)[0]), atol=1e-5)
 
 
+def _bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+#: Inputs of the pooling pins: random; rounded to a coarse grid, full of
+#: positive ties and all-zero windows; the same with two leading batch
+#: dims.  (shape, coarse)
+POOL_CASES = {"random": ((3, 8, 8, 5), False),
+              "coarse_grid": ((3, 8, 8, 5), True),
+              "two_leading_dims": ((2, 3, 8, 8, 5), True)}
+
+
+def _assert_ties(y):
+    """``y`` has windows whose positive max two taps share, and windows
+    whose max is at most 0 (all zero after a ReLU)."""
+    h, w, c = y.shape[-3:]
+    win = np.asarray(y).reshape(-1, h // 2, 2, w // 2, 2, c)
+    m = win.max(axis=(2, 4))
+    count = (win == m[:, :, None, :, None, :]).sum(axis=(2, 4))
+    assert np.any((count > 1) & (m > 0)) and np.any(m <= 0)
+
+
+def _pool_input(key, case):
+    shape, coarse = POOL_CASES[case]
+    x = jax.random.normal(key, shape)
+    return jnp.round(x * 2) / 2 if coarse else x
+
+
+@pytest.mark.kernel_oracle
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_max_pool2x2_vjp_equals_pool_flatten_to_the_bit(case):
+    """The fused path's pool against ``_pool_flatten``'s reshape pool:
+    the pooled values and the input gradient (a tie's gradient split over
+    its taps) equal to the bit, signed zeros included."""
+    from repro.models.cnn import _pool_flatten, max_pool2x2
+
+    ks = jax.random.split(jax.random.key(12), 2)
+    x = _pool_input(ks[0], case)
+    if POOL_CASES[case][1]:
+        _assert_ties(x)
+    lead = x.shape[:-3]
+    oracle = _pool_flatten
+    for _ in lead[1:]:
+        oracle = jax.vmap(oracle)
+    out, vjp = jax.vjp(lambda v: max_pool2x2(v).reshape(lead + (-1,)), x)
+    ref, ref_vjp = jax.vjp(oracle, x)
+    g = jax.random.normal(ks[1], ref.shape)
+    np.testing.assert_array_equal(_bits(out), _bits(ref))
+    np.testing.assert_array_equal(_bits(vjp(g)[0]), _bits(ref_vjp(g)[0]))
+
+
+def _relu_then_pool(params, x, mode):
+    """The CNN's features as the CPU path and the parent's fused path
+    compute them: both conv blocks with their ReLU, then
+    ``_pool_flatten``."""
+    from repro.models.cnn import _pool_flatten
+    for w, b in ((params["conv1"], params["b1"]),
+                 (params["conv2"], params["b2"])):
+        x = kd.conv3x3_bias_relu(x, w, b, mode=mode)
+    return _pool_flatten(x)
+
+
+@pytest.mark.kernel_oracle
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_fused_features_pool_before_relu_match_relu_then_pool(case):
+    """``_features_fused`` (interpret: block 2 pools its pre-activation,
+    then the ReLU) and its VJP with respect to the images and the four
+    conv parameters: equal in value to ReLU-then-pool on the same
+    convolution, and within the conv oracles' tolerances of the ``xla``
+    branch (the im2col conv).  The coarse cases round images and weights
+    to integers, on which both convolutions are exact, so the pool sees
+    positive ties and all-zero windows."""
+    from repro.models.cnn import _features_fused
+
+    shape, coarse = POOL_CASES[case]
+    lead = shape[:-3]
+    ks = jax.random.split(jax.random.key(13), 6)
+    grid = jnp.round if coarse else (lambda a: a)
+    dev = lead[:-1]
+    params = {"conv1": grid(jax.random.normal(ks[0], dev + (3, 3, 1, 4))),
+              "b1": grid(jax.random.normal(ks[1], dev + (4,))),
+              "conv2": grid(jax.random.normal(ks[2], dev + (3, 3, 4, 6))
+                            * 0.3),
+              "b2": grid(jax.random.normal(ks[3], dev + (6,)))}
+    x = grid(jax.random.normal(ks[4], lead + (8, 8, 1)))
+
+    def batched(fn):
+        # leading dims beyond the batch: one model each, as the engine's
+        # vmap over devices runs the step
+        for _ in lead[1:]:
+            fn = jax.vmap(fn)
+        return fn
+
+    def run(fn):
+        return jax.vjp(batched(fn), params, x)
+
+    if coarse:
+        y1 = batched(lambda p, v: kd.conv3x3_bias_relu(
+            v, p["conv1"], p["b1"], mode="xla"))(params, x)
+        _assert_ties(batched(lambda p, v: kd.conv3x3_bias_relu(
+            v, p["conv2"], p["b2"], mode="xla", relu=False))(params, y1))
+    out, vjp = run(lambda p, v: _features_fused(p, v, "interpret"))
+    same, same_vjp = run(lambda p, v: _relu_then_pool(p, v, "interpret"))
+    cpu, cpu_vjp = run(lambda p, v: _relu_then_pool(p, v, "xla"))
+    g = jax.random.normal(ks[5], out.shape)
+    got, ref, ref_cpu = vjp(g), same_vjp(g), cpu_vjp(g)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(same))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(cpu), atol=1e-5)
+    for a, b, c in zip(jax.tree.leaves(got), jax.tree.leaves(ref),
+                       jax.tree.leaves(ref_cpu)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4)
+
+
 @pytest.mark.kernel_oracle
 @pytest.mark.parametrize("mode", ["xla", "interpret"])
 @pytest.mark.parametrize("chunk", [16, 100, 250])
