@@ -18,12 +18,6 @@ orchestration it eliminates (per-edge dispatch, host-side batching, per-round
 syncs), and heavier local compute is identical FLOPs on both paths — see
 EXPERIMENTS.md §Perf for the step-budget sensitivity.
 
-The JSON also records the kernel-plane coverage of the engine rows — which
-round phases run as fused Pallas kernels under the resolved ``kernel_mode``
-(``repro.kernels.fused_phase_coverage``), the ``padded_flop_frac``-style
-column for the kernel plane.  On CPU ``auto`` resolves to ``xla`` and the
-fraction is 0.0; on a TPU the same rows report full fused coverage.
-
   PYTHONPATH=src python -m benchmarks.run --only engine --emit-json
 """
 from __future__ import annotations
@@ -33,7 +27,6 @@ import json
 
 from repro.configs.bhfl_cnn import REDUCED
 from repro.fl import BHFLSimulator, run_sweep
-from repro.kernels import fused_phase_coverage, resolve_kernel_mode
 
 from .common import Csv, interleaved_best_of
 
@@ -51,21 +44,9 @@ def _sim(**kw):
                          **KW, **kw)
 
 
-def kernel_plane_record(mode: str = "auto") -> dict:
-    """The kernel-plane coverage block shared by the BENCH_*.json emitters:
-    resolved mode, per-phase fused flags, and the fused fraction."""
-    resolved = resolve_kernel_mode(mode)
-    cov = fused_phase_coverage(mode)
-    frac = sum(cov.values()) / len(cov) if cov else 0.0
-    return {"kernel_mode": mode, "resolved": resolved,
-            "fused_phases": cov,
-            "fused_phase_frac": round(frac, 3)}
-
-
 def main(emit_json: bool = True) -> dict:
     csv = Csv("bench_engine")
-    kp = kernel_plane_record("auto")
-    csv.row("path", "seconds", "rounds_per_sec", "fused_phase_frac")
+    csv.row("path", "seconds", "rounds_per_sec")
 
     # head-to-head: legacy loop vs jitted engine, reps interleaved
     single = interleaved_best_of({
@@ -73,10 +54,8 @@ def main(emit_json: bool = True) -> dict:
         "jitted_engine": lambda: _sim().run(),
     }, REPS)
     t_legacy, t_engine = single["legacy_loop"], single["jitted_engine"]
-    csv.row("legacy_loop", f"{t_legacy:.2f}", f"{T_ROUNDS / t_legacy:.2f}",
-            "0.000")
-    csv.row("jitted_engine", f"{t_engine:.2f}", f"{T_ROUNDS / t_engine:.2f}",
-            f"{kp['fused_phase_frac']:.3f}")
+    csv.row("legacy_loop", f"{t_legacy:.2f}", f"{T_ROUNDS / t_legacy:.2f}")
+    csv.row("jitted_engine", f"{t_engine:.2f}", f"{T_ROUNDS / t_engine:.2f}")
 
     # Fig. 3-style grid: 2 straggler fractions x 2 seeds, one batched call
     overrides = [{"straggler_frac": f} for f in (0.2, 0.4)]
@@ -99,10 +78,9 @@ def main(emit_json: bool = True) -> dict:
     t_sweep_engine = sweep["engine_4pt_sweep"]
     sweep_rounds = n_pts * T_ROUNDS
     csv.row("legacy_4pt_sweep", f"{t_sweep_legacy:.2f}",
-            f"{sweep_rounds / t_sweep_legacy:.2f}", "0.000")
+            f"{sweep_rounds / t_sweep_legacy:.2f}")
     csv.row("engine_4pt_sweep", f"{t_sweep_engine:.2f}",
-            f"{sweep_rounds / t_sweep_engine:.2f}",
-            f"{kp['fused_phase_frac']:.3f}")
+            f"{sweep_rounds / t_sweep_engine:.2f}")
 
     out = {
         "setting": "REDUCED",
@@ -111,7 +89,6 @@ def main(emit_json: bool = True) -> dict:
         "steps_per_epoch": KW["steps_per_epoch"],
         "reps": REPS,
         "timing": "interleaved_best_of",
-        "kernel_plane": kp,
         "legacy_rounds_per_sec": round(T_ROUNDS / t_legacy, 3),
         "engine_rounds_per_sec": round(T_ROUNDS / t_engine, 3),
         "speedup": round(t_legacy / t_engine, 2),

@@ -27,11 +27,12 @@ compiles an ENTIRE run into one program:
     all grid points (vmap ``in_axes=None`` / ``shard_map`` replicated), so
     a multi-seed confidence grid holds the *distinct-seed* count in device
     memory, not one dataset copy per point,
-  * the hot path (warm HieAvg aggregation at both hierarchy layers, the
-    train-step SGD update) routes through the *kernel plane*
-    (``repro.kernels.dispatch``): a static ``kernel_mode`` knob selects
-    the fused Pallas kernels on TPU, the pure-XLA reference on CPU
-    ("auto"), or the Pallas interpreter for validation — and the
+  * the aggregation phases (HieAvg at both hierarchy layers, the
+    cold-boot means, the baselines) and the CNN conv blocks route through
+    the *kernel plane* (``repro.kernels.dispatch``): a static
+    ``kernel_mode`` knob selects the Pallas kernels and XLA's own
+    convolution on TPU, the pure-XLA reference and the im2col conv on
+    CPU ("auto"), or the Pallas interpreter for validation — and the
     donating entries (``run_engine_donated``; ``split_inputs`` /
     ``SHARED_DATA_FIELDS``) hand the per-run input planes to the
     compiled call so callers stop holding a second copy.
@@ -97,14 +98,13 @@ def train_epoch_body(params: PyTree, images: jnp.ndarray,
     valid mask is bitwise identical to passing ``None``.
 
     ``kernel_mode`` (resolved — ``"pallas"``/``"interpret"``/``"xla"``):
-    routes the inner SGD update through ``kernels.dispatch.sgd_update`` —
-    the fused one-pass kernel on accelerators, the original ``tree.map``
-    on the XLA path — and, when ``loss_fn`` is None (the default), the
-    conv blocks inside the loss through ``kernels.dispatch`` (XLA's own
-    convolution under a fused mode; ``cnn_loss_fast(kernel_mode=...)``).  An explicit ``loss_fn``
-    (``run_legacy``'s shifted-sum ``cnn_loss``) is used as-is.  The
-    padded-step mask folds into the kernel's scale (0 → exact identity)
-    so padding stays a numeric no-op on every path.
+    when ``loss_fn`` is None (the default), routes the conv blocks inside
+    the loss through ``kernels.dispatch`` (XLA's own convolution under a
+    fused mode; ``cnn_loss_fast(kernel_mode=...)``).  An explicit
+    ``loss_fn`` (``run_legacy``'s shifted-sum ``cnn_loss``) is used
+    as-is.  The update is XLA's ``w - scale * g`` per leaf on every
+    path; the padded-step mask folds into ``scale`` (0 → exact identity)
+    so padding stays a numeric no-op.
     """
     if loss_fn is None:
         loss_fn = partial(cnn_loss_fast, kernel_mode=kernel_mode)
@@ -121,7 +121,7 @@ def train_epoch_body(params: PyTree, images: jnp.ndarray,
         # reducer, whose op_name is relative to this body
         with jax.named_scope(telemetry.TRAIN):
             loss, g = jax.vmap(jax.value_and_grad(loss_fn))(ps, im, lb)
-            ps = kernel_dispatch.sgd_update(ps, g, scale, mode=kernel_mode)
+            ps = jax.tree.map(lambda w, gw: w - scale * gw, ps, g)
         return ps, loss
 
     images = jnp.swapaxes(images, 0, 1)                 # [steps, D, ...]
@@ -684,18 +684,17 @@ def _engine_body(inp: EngineInputs, *, aggregator: str = "hieavg",
     scan carry; ``inp.cohort_change`` resets a slot's pending/age when
     population-mode churn hands the slot to a new occupant.
 
-    ``kernel_mode`` routes every heavy round phase through the kernel
-    plane (``repro.kernels.dispatch.ROUND_PHASES``): the conv forward/
-    backward inside the train step, the SGD update, the warm HieAvg
-    edge/global aggregations, the cold-boot means, the FedAvg and
-    delayed-gradient aggregates (the "switched" set), and the post-scan
-    eval head.  ``"auto"`` resolves to the fused Pallas kernels on
-    TPU (the conv as XLA's own convolution) and the pure-XLA reference
-    on CPU (zero overhead);
-    ``"interpret"`` forces the Pallas interpreter (the CPU validation
-    path the parity tests pin); ``"xla"`` forces the reference.  Only
-    the legacy ``t_fedavg``/``d_fedavg`` baselines and the tiny history
-    bookkeeping stay XLA-always (not on the hot path).
+    ``kernel_mode`` routes the aggregation phases through the kernel
+    plane (``repro.kernels.dispatch``): the warm HieAvg edge/global
+    aggregations, the cold-boot means, the FedAvg and delayed-gradient
+    aggregates (the "switched" set), and the conv blocks of the train
+    step and the eval.  ``"auto"`` resolves to the Pallas kernels and
+    XLA's own convolution on TPU and to the pure-XLA reference with the
+    im2col conv on CPU (zero overhead); ``"interpret"`` forces the
+    Pallas interpreter (the CPU validation path the parity tests pin);
+    ``"xla"`` forces the reference.  The SGD update, the eval head, the
+    legacy ``t_fedavg``/``d_fedavg`` baselines and the tiny history
+    bookkeeping are XLA on every path.
     """
     kernel_mode = kernel_dispatch.resolve_kernel_mode(kernel_mode)
     T, K, N, J = inp.dev_masks.shape

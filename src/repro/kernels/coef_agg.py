@@ -15,7 +15,8 @@ fresh one.  The tiny [n] coefficient vectors (validity normalization,
 staleness discounts) are computed in XLA outside; the kernel does the
 heavy [n, L] weighted reduction in one HBM pass per leaf, identical
 tiling to ``hieavg_agg`` (grid over the flat parameter axis, [n, TILE]
-blocks in VMEM).
+blocks in VMEM).  ``fused_coef_aggregate`` and
+``fused_coef_aggregate_pair`` apply it per leaf of a stacked pytree.
 
 Zero-coefficient padded slots — sweep-fabric padding, invalid devices,
 all-miss cold rounds — contribute ``0 · w = 0`` exactly, so padding
@@ -31,12 +32,15 @@ for each aggregator live in ``kernels.dispatch``.
 from __future__ import annotations
 
 import functools
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .dispatch import default_interpret, out_struct
+
+PyTree = Any
 
 TILE = 2048
 
@@ -111,3 +115,31 @@ def coef_agg_pair(w: jnp.ndarray, aux: jnp.ndarray, ca: jnp.ndarray,
         interpret=interpret,
     )(w, aux, cvec)
     return agg[0, :l]
+
+
+def fused_coef_aggregate(stacked_w: PyTree, coef: jnp.ndarray, *,
+                         interpret: Optional[bool] = None) -> PyTree:
+    """``Σ_n coef[n] · w[n]`` per leaf in one fused pass (f32 outputs).
+
+    The shared core of the cold-boot means and FedAvg: the caller bakes
+    every normalization into ``coef`` (see ``dispatch``), so zero-coef
+    padded slots are exact no-ops.  Leaves ``[n, ...]`` → ``[...]``.
+    """
+    def one(w):
+        n = w.shape[0]
+        return coef_agg(w.reshape(n, -1), coef,
+                        interpret=interpret).reshape(w.shape[1:])
+
+    return jax.tree.map(one, stacked_w)
+
+
+def fused_coef_aggregate_pair(stacked_w: PyTree, aux: PyTree,
+                              ca: jnp.ndarray, cb: jnp.ndarray, *,
+                              interpret: Optional[bool] = None) -> PyTree:
+    """``Σ_n ca[n]·w[n] + cb[n]·aux[n]`` per leaf (delayed-grad mix)."""
+    def one(w, a):
+        n = w.shape[0]
+        return coef_agg_pair(w.reshape(n, -1), a.reshape(n, -1), ca, cb,
+                             interpret=interpret).reshape(w.shape[1:])
+
+    return jax.tree.map(one, stacked_w, aux)

@@ -1,6 +1,6 @@
-"""Kernel-plane backend dispatch — who runs a fused op, and how.
+"""Kernel-plane backend dispatch — who runs an aggregation, and how.
 
-Every compute hot-spot with a Pallas kernel has THREE executable forms:
+The engine's aggregation phases have THREE executable forms:
 
   * ``pallas``    — the compiled ``pallas_call`` (TPU; fails to lower
                     on CPU, which has no Mosaic backend),
@@ -8,37 +8,39 @@ Every compute hot-spot with a Pallas kernel has THREE executable forms:
                     (jax-level emulation: traceable, jittable, correct
                     everywhere, slower — the CPU validation path),
   * ``xla``       — the pure-jnp reference path (``core.hieavg``'s fused
-                    ``_mix_and_update`` tree.map / the plain SGD tree.map),
+                    ``_mix_and_update`` tree.map, ``core.baselines``),
                     which XLA fuses well on CPU.
+
+The CNN conv block has two: XLA's own convolution under the fused modes
+(``pallas``/``interpret``), the im2col einsum of ``models.cnn`` under
+``xla``.  The train step's SGD update and the eval head have one, plain
+XLA, and are not dispatched.
 
 This module is the single place that picks between them.  The knob is a
 ``kernel_mode`` string threaded ``BHFLSimulator``/``run_sweep`` →
 ``run_engine`` (like ``history_dtype``):
 
   * ``"auto"``      — ``pallas`` on TPU, ``xla`` on CPU.  The default
-                      everywhere: accelerators get the one-HBM-pass fused
-                      kernels, CPU keeps the XLA path with zero overhead
-                      (never the interpreter loop).
+                      everywhere: CPU keeps the XLA path with zero
+                      overhead (never the interpreter loop).
   * ``"pallas"`` / ``"interpret"`` / ``"xla"`` — force a path (tests pin
                       ``interpret`` vs ``xla`` engine parity on CPU).
 
 ``default_interpret()`` is the companion policy for DIRECT kernel calls
-(``ops.flash_attention``, ``hieavg_agg`` benchmarks): when the caller
-passes ``interpret=None`` the kernel compiles on TPU and interprets on
-CPU — previously ``interpret=True`` was hard-coded "until the launch layer
-flips it off", which nothing ever did, so real hardware silently ran the
-interpreter.
+(``flash_attention``, tests): when the caller passes ``interpret=None``
+the kernel compiles on TPU and interprets on CPU.
 
-Layering: this module imports only jax + ``core.hieavg`` at module level
-and pulls the kernel wrappers (``ops``) in lazily, so the kernel modules
-may import ``default_interpret`` from here without a cycle.
+Layering: this module imports only jax + ``core`` at module level and
+pulls the kernel modules in lazily, so they may import
+``default_interpret`` and ``out_struct`` from here without a cycle.
 
-The dispatch entry points (``edge_aggregate_batched``,
-``global_aggregate``, ``sgd_update``) mirror the engine's calling
-conventions exactly — batched ``[N, J, ...]`` stacked trees with validity
-masks, traced ``gamma0``/``lam`` scalars — and guarantee the same
-padded-slot no-op contract as the XLA path (zero part-weight padding
-contributes exactly nothing; see docs/ARCHITECTURE.md §Kernel plane).
+The aggregation entries (``edge_aggregate_batched``,
+``global_aggregate``, the cold-boot means and the baselines) mirror the
+engine's calling conventions exactly — batched ``[N, J, ...]`` stacked
+trees with validity masks, traced ``gamma0``/``lam`` scalars — and
+guarantee the same padded-slot no-op contract as the XLA path (zero
+part-weight padding contributes exactly nothing; see
+docs/ARCHITECTURE.md §Kernel plane).
 """
 from __future__ import annotations
 
@@ -52,24 +54,6 @@ from repro.core import baselines, hieavg
 from repro.core.hieavg import History
 
 PyTree = Any
-
-#: The engine round phases with a fused kernel, in round order.  Under a
-#: fused mode (``pallas``/``interpret``) every phase listed here runs in
-#: a Pallas kernel, but the conv, which runs XLA's own convolution fused
-#: with its bias and ReLU; under ``xla`` all run the pure-jnp reference
-#: paths.
-#: (``t_fedavg``/``d_fedavg`` — legacy baselines outside the switched
-#: set — and the tiny history-bookkeeping updates stay XLA by design.)
-ROUND_PHASES = ("train_conv_fwd_bwd", "sgd_update", "warm_edge_aggregate",
-                "warm_global_aggregate", "cold_boot_aggregate",
-                "fedavg_aggregate", "delayed_grad_aggregate", "eval_head")
-
-
-def fused_phase_coverage(mode: str = "auto") -> dict:
-    """Which round phases run fused under ``mode`` (resolved) — the
-    benchmarks' coverage column (`padded_flop_frac`-style)."""
-    fused = resolve_kernel_mode(mode) in ("pallas", "interpret")
-    return {phase: fused for phase in ROUND_PHASES}
 
 #: The accepted ``kernel_mode`` values, in resolution order.
 KERNEL_MODES = ("auto", "pallas", "interpret", "xla")
@@ -138,8 +122,8 @@ def edge_aggregate_batched(stacked_w: PyTree, mask: jnp.ndarray,
     if mode == "xla":
         return hieavg.edge_aggregate_batched(stacked_w, mask, history,
                                              valid, gamma0, lam, normalize)
-    from . import ops
-    return ops.fused_edge_aggregate_batched(
+    from .hieavg_agg import fused_edge_aggregate_batched
+    return fused_edge_aggregate_batched(
         stacked_w, mask, history, valid, gamma0, lam, normalize,
         interpret=_interpret(mode))
 
@@ -154,27 +138,9 @@ def global_aggregate(stacked_w: PyTree, mask: jnp.ndarray, history: History,
     if mode == "xla":
         return hieavg.aggregate(stacked_w, mask, history, part_weights,
                                 gamma0, lam, normalize)
-    from . import ops
-    return ops.fused_mix_and_update(stacked_w, mask, history, part_weights,
-                                    gamma0, lam, normalize,
-                                    interpret=_interpret(mode))
-
-
-def sgd_update(params: PyTree, grads: PyTree, scale, *,
-               mode: str = "auto") -> PyTree:
-    """The train-step inner update ``w - scale * g`` per leaf.
-
-    ``scale`` is the (traced) lr × step-validity product — a padded sweep
-    step passes 0 and the update is exact identity on every path.  The
-    fused path does the read-modify-write in one pass per ``[D, L]`` leaf
-    (oracle: ``ref.sgd_update_ref``); ``xla`` is the engine's original
-    ``tree.map``, bit-identical to what ``run_engine`` always did.
-    """
-    mode = resolve_kernel_mode(mode)
-    if mode == "xla":
-        return jax.tree.map(lambda w, g: w - scale * g, params, grads)
-    from . import ops
-    return ops.fused_sgd_update(params, grads, scale,
+    from .hieavg_agg import fused_mix_and_update
+    return fused_mix_and_update(stacked_w, mask, history, part_weights,
+                                gamma0, lam, normalize,
                                 interpret=_interpret(mode))
 
 
@@ -182,7 +148,7 @@ def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                       mode: str = "auto", relu: bool = True) -> jnp.ndarray:
     """The CNN conv block ``relu(conv3x3_same(x, w) + b)``; with
     ``relu=False`` the pre-activation ``conv3x3_same(x, w) + b``, for a
-    block that pools before its ReLU (``models.cnn._features_fused``).
+    block that pools before its ReLU (``models.cnn._features``).
 
     x: [..., H, W, Cin]; w: [3, 3, Cin, Cout]; b: [Cout].  The fused
     modes run XLA's own convolution, which XLA fuses with the bias and
@@ -214,23 +180,6 @@ def conv3x3_bias_relu(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
     return y.reshape(x.shape[:-1] + y.shape[-1:])
 
 
-def eval_head(feats: jnp.ndarray, wmat: jnp.ndarray, bias: jnp.ndarray,
-              labels: jnp.ndarray, *, mode: str = "auto") -> jnp.ndarray:
-    """Correct-prediction count of the classifier head (scalar int32).
-
-    The fused path folds logits → argmax → compare → count into the
-    matmul tiles; ``xla`` is the plain three-op chain.
-    """
-    mode = resolve_kernel_mode(mode)
-    if mode == "xla":
-        logits = feats @ wmat + bias
-        pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jnp.sum((pred == labels.astype(jnp.int32)).astype(jnp.int32))
-    from . import ops
-    return ops.eval_head(feats, wmat, bias, labels,
-                         interpret=_interpret(mode))
-
-
 # ------------------------------------------------- cold boot + baselines
 # All three entries below are instances of the generalized coefficient
 # aggregate (``kernels.coef_agg``): the tiny [n] coefficient recipe is
@@ -249,11 +198,10 @@ def edge_aggregate_cold_batched(stacked_w: PyTree, valid: jnp.ndarray, *,
     mode = resolve_kernel_mode(mode)
     if mode == "xla":
         return hieavg.edge_aggregate_cold_batched(stacked_w, valid)
-    from . import ops
+    from .coef_agg import fused_coef_aggregate
     v = valid.astype(jnp.float32)
     pw = v / jnp.maximum(jnp.sum(v, axis=-1, keepdims=True), 1e-12)
-    fn = functools.partial(ops.fused_coef_aggregate,
-                           interpret=_interpret(mode))
+    fn = functools.partial(fused_coef_aggregate, interpret=_interpret(mode))
     return jax.vmap(fn)(stacked_w, pw)
 
 
@@ -264,11 +212,10 @@ def global_aggregate_cold(stacked_w: PyTree, j_per_edge: jnp.ndarray, *,
     mode = resolve_kernel_mode(mode)
     if mode == "xla":
         return hieavg.global_aggregate_cold(stacked_w, j_per_edge)
-    from . import ops
+    from .coef_agg import fused_coef_aggregate
     pw = j_per_edge.astype(jnp.float32) \
         / jnp.maximum(jnp.sum(j_per_edge), 1e-12)
-    return ops.fused_coef_aggregate(stacked_w, pw,
-                                    interpret=_interpret(mode))
+    return fused_coef_aggregate(stacked_w, pw, interpret=_interpret(mode))
 
 
 def fedavg(stacked_w: PyTree, part_weights: jnp.ndarray, *,
@@ -277,10 +224,9 @@ def fedavg(stacked_w: PyTree, part_weights: jnp.ndarray, *,
     mode = resolve_kernel_mode(mode)
     if mode == "xla":
         return baselines.fedavg(stacked_w, part_weights)
-    from . import ops
+    from .coef_agg import fused_coef_aggregate
     coef = part_weights / jnp.maximum(jnp.sum(part_weights), 1e-12)
-    return ops.fused_coef_aggregate(stacked_w, coef,
-                                    interpret=_interpret(mode))
+    return fused_coef_aggregate(stacked_w, coef, interpret=_interpret(mode))
 
 
 def delayed_grad(stacked_w: PyTree, mask: jnp.ndarray, pending: PyTree,
@@ -299,14 +245,14 @@ def delayed_grad(stacked_w: PyTree, mask: jnp.ndarray, pending: PyTree,
     if mode == "xla":
         return baselines.delayed_grad(stacked_w, mask, pending, age,
                                       beta, delta, part_weights)
-    from . import ops
+    from .coef_agg import fused_coef_aggregate_pair
     m = mask.astype(jnp.float32)
     k_prime = age + 1.0
     stale_c = (beta ** k_prime) * (k_prime <= delta).astype(jnp.float32)
     coef = part_weights * (m + (1.0 - m) * stale_c)
     coef = coef / jnp.maximum(jnp.sum(coef), 1e-12)
-    agg = ops.fused_coef_aggregate_pair(stacked_w, pending, coef * m,
-                                        coef * (1.0 - m),
-                                        interpret=_interpret(mode))
+    agg = fused_coef_aggregate_pair(stacked_w, pending, coef * m,
+                                    coef * (1.0 - m),
+                                    interpret=_interpret(mode))
     new_age = (age + 1.0) * (1.0 - m)
     return agg, stacked_w, new_age

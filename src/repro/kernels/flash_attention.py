@@ -8,7 +8,8 @@ Covers full-causal and sliding-window (the serving hot-spot for the 32k /
 
 Grid: (nq, nk), kv innermost.  Blocks: q [BQ, D], k/v [BK, D] — BQ=BK=256
 rows × D≤256 f32 lanes ≈ 0.26 MB per operand block; MXU-aligned (multiples
-of 128).  GQA/batch are handled by ``vmap`` in ops.py (prepended grid dims).
+of 128).  ``flash_attention`` is the multi-head GQA front end: batch,
+kv-head and group dims are ``vmap``ped (Pallas prepends them as grid dims).
 """
 from __future__ import annotations
 
@@ -120,3 +121,31 @@ def flash_attention_1h(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         interpret=interpret,
     )(q, k, v)
     return out[:sq, :d]
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "window", "q_offset",
+                                             "interpret"))
+def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                    causal: bool = True, window: Optional[int] = None,
+                    q_offset: int = 0, interpret: Optional[bool] = None
+                    ) -> jnp.ndarray:
+    """GQA flash attention. q [B,Sq,H,Dh]; k/v [B,Skv,Hkv,Dh] -> like q.
+
+    Matches ``repro.models.attention._sdpa`` semantics (scale 1/sqrt(Dh)).
+    """
+    b, sq, h, dh = q.shape
+    hkv = k.shape[2]
+    g = h // hkv
+    qg = q.reshape(b, sq, hkv, g, dh)
+
+    fn = functools.partial(flash_attention_1h, causal=causal, window=window,
+                           q_offset=q_offset, interpret=interpret)
+    # [B, Hkv, G] prepended as grid dims by vmap (outermost applied last;
+    # each vmap strips the leading mapped axis of the operands it maps)
+    fn = jax.vmap(fn, in_axes=(0, None, None))        # G (q only)
+    fn = jax.vmap(fn, in_axes=(0, 0, 0))              # Hkv
+    fn = jax.vmap(fn, in_axes=(0, 0, 0))              # B
+    qb = jnp.moveaxis(qg, 1, -2)                      # [B, Hkv, G, Sq, Dh]
+    kb = jnp.moveaxis(k, 1, -2)                       # [B, Hkv, Skv, Dh]
+    out = fn(qb, kb, jnp.moveaxis(v, 1, -2))          # [B, Hkv, G, Sq, Dh]
+    return jnp.moveaxis(out, -2, 1).reshape(b, sq, h, dh)

@@ -15,21 +15,28 @@ TILE = 2048 f32 lanes → ≤ 0.8 MB/operand·block, comfortably inside the
 The per-participant coefficients (mask, γ-decay, 1/J weights) are tiny [n]
 vectors computed outside and broadcast in VMEM.
 
-Batched callers (the engine's ``[N, J, ...]`` dense layout, the sweep
-fabric's stacked ``[P]`` point axis) ``vmap`` this kernel — Pallas
-prepends the mapped axes as grid dimensions (see
-``ops.fused_edge_aggregate_batched``).  Backend selection (compiled vs
-interpreter vs the XLA reference path) lives in ``kernels.dispatch``.
+The pytree entries ``fused_mix_and_update`` (eq. 4/5 on one layer) and
+``fused_edge_aggregate_batched`` (eq. 4 for all N edges of the engine's
+``[N, J, ...]`` dense layout) flatten each leaf to ``[n, L]`` and
+``vmap`` the kernel over the edge axis — Pallas prepends the mapped axes
+(and the sweep fabric's stacked ``[P]`` point axis) as grid dimensions.
+Backend selection (compiled vs interpreter vs the XLA reference path)
+lives in ``kernels.dispatch``.
 """
 from __future__ import annotations
 
 import functools
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.hieavg import History
+
 from .dispatch import default_interpret, out_struct
+
+PyTree = Any
 
 TILE = 2048
 
@@ -109,3 +116,74 @@ def hieavg_agg(w: jnp.ndarray, prev: jnp.ndarray, dmean: jnp.ndarray,
         interpret=interpret,
     )(*ops)
     return agg[0, :l], nprev[:, :l], ndmean[:, :l]
+
+
+def fused_mix_and_update(stacked_w: PyTree, mask: jnp.ndarray,
+                         history: History, part_weights: jnp.ndarray,
+                         gamma0, lam, normalize: bool = False, *,
+                         interpret: Optional[bool] = None
+                         ) -> tuple[PyTree, History]:
+    """Kernel-fused ``hieavg._mix_and_update`` (eq. 4/5) on [n, ...] leaves.
+
+    ``part_weights``/``gamma0``/``lam`` may be traced (the engine sweeps
+    decay factors as data) — the tiny per-participant coefficient vectors
+    are computed in XLA and broadcast into the kernel, which does the
+    heavy [n, L] mix + history update in one HBM pass per leaf.  An
+    all-zero ``part_weights`` row (sweep-fabric padding) contributes
+    exactly nothing.  Returns (aggregate, updated History) — allclose to
+    the core path; no jit boundary, composes under vmap/scan.
+    """
+    m = mask.astype(jnp.float32)
+    gamma = gamma0 * lam ** (history.miss_count + 1.0)    # k' >= 1
+    coef = part_weights * (m + (1.0 - m) * gamma)
+    if normalize:
+        coef = coef / jnp.maximum(jnp.sum(coef), 1e-12)
+    coef_present = coef * m
+    coef_est = coef * (1.0 - m)
+    n = mask.shape[0]
+
+    leaves_w, treedef = jax.tree_util.tree_flatten(stacked_w)
+    leaves_p = treedef.flatten_up_to(history.prev_w)
+    leaves_d = treedef.flatten_up_to(history.delta_mean)
+
+    aggs, nprevs, ndmeans = [], [], []
+    for w, p, d in zip(leaves_w, leaves_p, leaves_d):
+        flat = (n, -1)
+        a, np_, nd = hieavg_agg(w.reshape(flat), p.reshape(flat),
+                                d.reshape(flat), mask, coef_present,
+                                coef_est, history.n_obs,
+                                interpret=interpret)
+        aggs.append(a.reshape(w.shape[1:]))
+        nprevs.append(np_.reshape(p.shape))
+        ndmeans.append(nd.reshape(d.shape))
+
+    new_hist = History(
+        prev_w=jax.tree_util.tree_unflatten(treedef, nprevs),
+        delta_mean=jax.tree_util.tree_unflatten(treedef, ndmeans),
+        n_obs=history.n_obs + m,
+        miss_count=(history.miss_count + 1.0) * (1.0 - m),
+    )
+    return jax.tree_util.tree_unflatten(treedef, aggs), new_hist
+
+
+def fused_edge_aggregate_batched(stacked_w: PyTree, mask: jnp.ndarray,
+                                 history: History, valid: jnp.ndarray,
+                                 gamma0, lam, normalize: bool = False, *,
+                                 interpret: Optional[bool] = None
+                                 ) -> tuple[PyTree, History]:
+    """Eq. (4) for ALL N edges through the fused kernel in one vmapped call.
+
+    Mirrors ``hieavg.edge_aggregate_batched`` exactly: stacked_w leaves
+    ``[N, J, ...]``, mask/valid ``[N, J]``, per-edge part weights
+    ``valid / J_e`` (zero on padded slots, so padding stays a numeric
+    no-op).  The edge axis is vmapped over the kernel, so one
+    ``pallas_call`` per leaf covers the whole dense layout.
+    """
+    v = valid.astype(jnp.float32)
+    pw = v / jnp.maximum(jnp.sum(v, axis=-1, keepdims=True), 1.0)
+
+    def one_edge(w, m, h, p):
+        return fused_mix_and_update(w, m, h, p, gamma0, lam, normalize,
+                                    interpret=interpret)
+
+    return jax.vmap(one_edge)(stacked_w, mask, history, pw)

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+"""Pure-jnp oracles for every Pallas kernel, the conv block and the eval
+head (the allclose targets).
 
 These are the *semantics* contracts: kernels must match them on every
 shape/dtype the tests sweep.
@@ -39,19 +40,6 @@ def hieavg_agg_ref(w: jnp.ndarray, prev: jnp.ndarray, dmean: jnp.ndarray,
             new_dmean.astype(dmean.dtype))
 
 
-# -------------------------------------------------------------- sgd_update
-def sgd_update_ref(w: jnp.ndarray, g: jnp.ndarray, scale) -> jnp.ndarray:
-    """Masked SGD update on one flat leaf: ``w - scale * g``.
-
-    ``scale`` is a scalar (lr × step-validity — 0 for a padded sweep step,
-    which makes the update an exact identity).  Math in f32, output cast
-    back to ``w.dtype``.
-    """
-    f32 = jnp.float32
-    s = jnp.asarray(scale, f32)
-    return (w.astype(f32) - s * g.astype(f32)).astype(w.dtype)
-
-
 # ------------------------------------------------------- conv3x3_bias_relu
 def conv3x3_bias_relu_ref(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray
                           ) -> jnp.ndarray:
@@ -76,7 +64,7 @@ def conv3x3_bias_relu_ref(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray
 # ---------------------------------------------------------------- eval_head
 def eval_head_ref(feats: jnp.ndarray, wmat: jnp.ndarray, bias: jnp.ndarray,
                   labels: jnp.ndarray) -> jnp.ndarray:
-    """Fused eval oracle: correct-count of the classifier head.
+    """Eval oracle: correct-count of the classifier head.
 
     feats: [M, F]; wmat: [F, C]; bias: [C]; labels: [M] int.  Returns the
     scalar int32 count of rows where ``argmax(feats @ wmat + bias)``

@@ -125,7 +125,7 @@ def _sdpa(q, k, v, *, causal: bool, window=None, q_offset: int = 0,
     b, sq, h, dh = q.shape
     skv = k.shape[1]
     if USE_FLASH_KERNEL and bias is None and sq > 1:
-        from repro.kernels.ops import flash_attention
+        from repro.kernels.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, window=window,
                                q_offset=q_offset)
     if bias is not None:

@@ -33,7 +33,8 @@ def _conv3x3_same(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     28x28x32, on a Xeon host: 19.6 s for lax.conv against 0.94 s for the
     im2col einsum with per-device weights; with weights shared by all
     devices lax.conv wins, 0.53 s against 1.21 s.  A TPU runs the
-    per-device lax.conv well: ``kernels.dispatch`` runs it there.
+    per-device lax.conv well: ``kernels.dispatch`` runs it there, and the
+    engine's CPU path runs ``_conv3x3_same_im2col``.
     x: [..., H, W, Cin]; w: [3, 3, Cin, Cout].
     """
     h, wd = x.shape[-3], x.shape[-2]
@@ -103,8 +104,8 @@ def _conv3x3_same_im2col(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
 def _pool_flatten(x: jnp.ndarray) -> jnp.ndarray:
     # 2x2 stride-2 max-pool via reshape — identical to reduce_window but its
     # gradient avoids SelectAndScatter, which is pathologically slow on CPU.
-    # The CPU path's pool, and the oracle of the fused path's
-    # ``max_pool2x2`` (tests/test_kernel_plane.py).
+    # The legacy forward's pool (``cnn_apply``), and the oracle of the
+    # engine's ``max_pool2x2`` (tests/test_kernel_plane.py).
     b, h, w_, c = x.shape
     x = x.reshape(b, h // 2, 2, w_ // 2, 2, c).max(axis=(2, 4))
     return x.reshape(x.shape[0], -1)
@@ -177,20 +178,13 @@ def _max_pool_bwd(mask, g):
 _max_pool2x2.defvjp(_max_pool_fwd, _max_pool_bwd)
 
 
-def _apply(params: dict, images: jnp.ndarray, conv) -> jnp.ndarray:
-    x = images
-    for w, b in ((params["conv1"], params["b1"]),
-                 (params["conv2"], params["b2"])):
-        x = jax.nn.relu(conv(x, w) + b)
-    x = _pool_flatten(x)
-    return x @ params["dense"] + params["b3"]
-
-
-def _features_fused(params: dict, images: jnp.ndarray, kernel_mode: str
-                    ) -> jnp.ndarray:
-    """Pooled/flattened features with the conv blocks routed through
-    ``kernels.dispatch.conv3x3_bias_relu`` (XLA's own convolution, fused
-    with its bias and ReLU).
+def _features(params: dict, images: jnp.ndarray, kernel_mode: str
+              ) -> jnp.ndarray:
+    """Pooled/flattened features of the engine's forward, on every
+    backend: the conv blocks go through
+    ``kernels.dispatch.conv3x3_bias_relu``, which reads ``kernel_mode``
+    (XLA's own convolution under a fused mode, the im2col einsum under
+    ``xla``).
 
     Block 2 pools its pre-activation and applies the ReLU to the pooled
     tensor: the same values as ``_pool_flatten(relu(...))``, gradients
@@ -209,25 +203,21 @@ def _features_fused(params: dict, images: jnp.ndarray, kernel_mode: str
 
 def cnn_apply(params: dict, images: jnp.ndarray) -> jnp.ndarray:
     """images [B, H, W, C] -> logits [B, n_classes]."""
-    return _apply(params, images, _conv3x3_same)
+    x = images
+    for w, b in ((params["conv1"], params["b1"]),
+                 (params["conv2"], params["b2"])):
+        x = jax.nn.relu(_conv3x3_same(x, w) + b)
+    x = _pool_flatten(x)
+    return x @ params["dense"] + params["b3"]
 
 
 def cnn_apply_fast(params: dict, images: jnp.ndarray,
                    kernel_mode: str = "xla") -> jnp.ndarray:
-    """``cnn_apply`` with the im2col conv — the engine's training path.
-
-    ``kernel_mode`` (resolved or ``"auto"``) routes the conv blocks:
-    ``"xla"`` (the default, bit-identical to what this function always
-    did) keeps the plain im2col einsum; the fused modes (the TPU's) run
-    them as XLA's own convolution, through ``kernels.dispatch``.  The
-    engine threads its resolved mode here.
-    """
-    from repro.kernels import dispatch as _kd
-    mode = _kd.resolve_kernel_mode(kernel_mode)
-    if mode == "xla":
-        return _apply(params, images, _conv3x3_same_im2col)
-    feats = _features_fused(params, images, mode)
-    return feats @ params["dense"] + params["b3"]
+    """``cnn_apply``'s function as the engine trains it (``_features``):
+    ``kernel_mode`` (resolved or ``"auto"``) picks the conv blocks'
+    backend and nothing else."""
+    return _features(params, images, kernel_mode) @ params["dense"] \
+        + params["b3"]
 
 
 def _loss(apply, params, images, labels):
@@ -261,21 +251,10 @@ def cnn_accuracy(params: dict, images: jnp.ndarray, labels: jnp.ndarray
 def cnn_correct_fast(params: dict, images: jnp.ndarray, labels: jnp.ndarray,
                      kernel_mode: str = "xla") -> jnp.ndarray:
     """Int32 count of correct predictions on the ``cnn_apply_fast``
-    forward (the engine's eval path); a label of -1 never counts.
-
-    Under a fused ``kernel_mode`` the whole eval runs kernel-routed: conv
-    blocks as XLA's own convolution, then the classifier head as one
-    logits → argmax → correct-count pass (``kernels.dispatch.eval_head``)
-    — the logits buffer never materializes.
-    """
-    from repro.kernels import dispatch as _kd
-    mode = _kd.resolve_kernel_mode(kernel_mode)
-    if mode == "xla":
-        pred = jnp.argmax(cnn_apply_fast(params, images), -1)
-        return jnp.sum((pred == labels).astype(jnp.int32))
-    feats = _features_fused(params, images, mode)
-    return _kd.eval_head(feats, params["dense"], params["b3"], labels,
-                         mode=mode)
+    forward (the engine's eval path): logits, argmax, compare, count.  A
+    label of -1 never counts."""
+    pred = jnp.argmax(cnn_apply_fast(params, images, kernel_mode), -1)
+    return jnp.sum((pred == labels).astype(jnp.int32))
 
 
 def cnn_accuracy_fast(params: dict, images: jnp.ndarray, labels: jnp.ndarray,

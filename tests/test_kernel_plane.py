@@ -2,9 +2,10 @@
 
 Three pin groups (see docs/ARCHITECTURE.md §Kernel plane):
 
-  * kernel oracles — ``hieavg_agg`` / ``sgd_update`` against their
+  * kernel oracles — ``hieavg_agg`` / ``coef_agg`` against their
     pure-jnp refs across tile-tail shapes (L not a multiple of TILE,
-    L < TILE) and the mixed-dtype bf16 ``history_dtype`` layout,
+    L < TILE) and the mixed-dtype bf16 ``history_dtype`` layout; the
+    conv block, the pool and the plain eval head against theirs,
   * engine parity — ``kernel_mode="interpret"`` (the fused kernels
     through the Pallas interpreter, the only kernel execution CPU has)
     must reproduce the pure-XLA engine on standalone runs AND across a
@@ -31,13 +32,10 @@ from repro.fl.engine import (SHARED_DATA_FIELDS, run_engine,
 from repro.kernels import dispatch as kd
 from repro.kernels.coef_agg import TILE as CTILE
 from repro.kernels.coef_agg import coef_agg, coef_agg_pair
-from repro.kernels.eval_head import eval_head
-from repro.kernels.ops import (fused_edge_aggregate_batched,
-                               fused_mix_and_update)
+from repro.kernels.hieavg_agg import TILE, fused_edge_aggregate_batched, \
+    fused_mix_and_update
 from repro.kernels.ref import (coef_agg_pair_ref, coef_agg_ref,
-                               conv3x3_bias_relu_ref, eval_head_ref,
-                               sgd_update_ref)
-from repro.kernels.sgd_update import TILE, sgd_update
+                               conv3x3_bias_relu_ref, eval_head_ref)
 
 TINY = dataclasses.replace(REDUCED, t_global_rounds=3, n_edges=3,
                            j_per_edge=3, image_hw=8)
@@ -81,42 +79,24 @@ def test_unknown_kernel_mode_raises_naming_the_choices():
 # Every test in this group is marked ``kernel_oracle``: CI runs them as a
 # dedicated interpret-mode oracle-parity job (`pytest -m kernel_oracle`).
 @pytest.mark.kernel_oracle
-@settings(max_examples=10, deadline=None)
-@given(n=st.integers(1, 9),
-       l=st.sampled_from([1, 7, 100, TILE - 1, TILE, TILE + 1, 3 * TILE]),
-       seed=st.integers(0, 99))
-def test_sgd_update_matches_ref_on_tile_tails(n, l, seed):
-    ks = jax.random.split(jax.random.key(seed), 2)
-    w = jax.random.normal(ks[0], (n, l))
-    g = jax.random.normal(ks[1], (n, l))
-    got = sgd_update(w, g, jnp.float32(0.37), interpret=True)
-    ref = sgd_update_ref(w, g, 0.37)
-    # 1-ulp slack: XLA may contract the multiply-subtract into an FMA in
-    # one lowering and not the other
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-6, atol=1e-7)
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+def test_sgd_update_zero_scale_is_exact_identity(mode):
+    """scale = lr x step-validity: an epoch whose steps are all padded
+    (``step_ok`` 0) must leave the parameters bitwise unchanged."""
+    from repro.fl.engine import train_epoch_body
+    from repro.models import cnn_specs, init_from_specs
 
-
-@pytest.mark.kernel_oracle
-def test_sgd_update_zero_scale_is_exact_identity():
-    """scale = lr x step-validity: a padded sweep step (0) must be an
-    exact no-op, bitwise."""
-    w = jax.random.normal(jax.random.key(0), (4, 333))
-    g = jax.random.normal(jax.random.key(1), (4, 333)) * 1e3
-    got = sgd_update(w, g, jnp.float32(0.0), interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
-
-
-@pytest.mark.kernel_oracle
-def test_sgd_update_bf16_storage():
-    w = jax.random.normal(jax.random.key(0), (3, 100), jnp.bfloat16)
-    g = jax.random.normal(jax.random.key(1), (3, 100), jnp.bfloat16)
-    got = sgd_update(w, g, jnp.float32(0.1), interpret=True)
-    assert got.dtype == jnp.bfloat16
-    ref = sgd_update_ref(w, g, 0.1)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(ref, np.float32), rtol=1e-6,
-                               atol=1e-7)
+    ks = jax.random.split(jax.random.key(0), 4)
+    d, steps, b = 3, 2, 4
+    one = init_from_specs(cnn_specs(image_hw=8, c1=4, c2=8), ks[0])
+    params = jax.tree.map(
+        lambda a: jnp.stack([a + 0.1 * i for i in range(d)]), one)
+    images = jax.random.normal(ks[1], (d, steps, b, 8, 8, 1))
+    labels = jax.random.randint(ks[2], (d, steps, b), 0, 10)
+    got, _ = train_epoch_body(params, images, labels, jnp.float32(1e3),
+                              step_ok=jnp.zeros((steps,)), kernel_mode=mode)
+    for w, g in zip(jax.tree.leaves(params), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
 
 
 @pytest.mark.kernel_oracle
@@ -180,9 +160,10 @@ def test_fused_batched_matches_core_batched_with_padding():
 
 
 @pytest.mark.kernel_oracle
-def test_fused_global_matches_core_traced_weights():
+@pytest.mark.parametrize("normalize", [False, True])
+def test_fused_global_matches_core_traced_weights(normalize):
     """Eq. (5) with J-weighted traced part weights — the engine's global
-    layer call."""
+    layer call, with and without the coefficients normalized."""
     n = 3
     w = {"p": jax.random.normal(jax.random.key(9), (n, 7, 2))}
     hist = hieavg.init_history(w)
@@ -192,9 +173,10 @@ def test_fused_global_matches_core_traced_weights():
     pw = j_arr / jnp.sum(j_arr)
     mask = jnp.asarray([True, False, True])
     a_ref, _ = hieavg.aggregate(w, mask, hist, pw, jnp.float32(0.9),
-                                jnp.float32(0.9), True)
+                                jnp.float32(0.9), normalize)
     a_got, _ = fused_mix_and_update(w, mask, hist, pw, jnp.float32(0.9),
-                                    jnp.float32(0.9), True, interpret=True)
+                                    jnp.float32(0.9), normalize,
+                                    interpret=True)
     np.testing.assert_allclose(np.asarray(a_got["p"]),
                                np.asarray(a_ref["p"]), atol=1e-6)
 
@@ -323,9 +305,8 @@ def test_max_pool2x2_vjp_equals_pool_flatten_to_the_bit(case):
 
 
 def _relu_then_pool(params, x, mode):
-    """The CNN's features as the CPU path and the parent's fused path
-    compute them: both conv blocks with their ReLU, then
-    ``_pool_flatten``."""
+    """The CNN's features as ``cnn_apply`` orders them: both conv blocks
+    with their ReLU, then ``_pool_flatten``."""
     from repro.models.cnn import _pool_flatten
     for w, b in ((params["conv1"], params["b1"]),
                  (params["conv2"], params["b2"])):
@@ -336,14 +317,15 @@ def _relu_then_pool(params, x, mode):
 @pytest.mark.kernel_oracle
 @pytest.mark.parametrize("case", sorted(POOL_CASES))
 def test_fused_features_pool_before_relu_match_relu_then_pool(case):
-    """``_features_fused`` (interpret: block 2 pools its pre-activation,
-    then the ReLU) and its VJP with respect to the images and the four
-    conv parameters: equal in value to ReLU-then-pool on the same
-    convolution, and within the conv oracles' tolerances of the ``xla``
-    branch (the im2col conv).  The coarse cases round images and weights
-    to integers, on which both convolutions are exact, so the pool sees
+    """``_features`` (block 2 pools its pre-activation, then the ReLU)
+    and its VJP with respect to the images and the four conv parameters,
+    under both conv backends: equal to the bit to ReLU-then-pool on the
+    same convolution, and the interpret branch (XLA's convolution, the
+    TPU's) within the conv oracles' tolerances of the ``xla`` branch
+    (the im2col conv).  The coarse cases round images and weights to
+    integers, on which both convolutions are exact, so the pool sees
     positive ties and all-zero windows."""
-    from repro.models.cnn import _features_fused
+    from repro.models.cnn import _features
 
     shape, coarse = POOL_CASES[case]
     lead = shape[:-3]
@@ -372,16 +354,20 @@ def test_fused_features_pool_before_relu_match_relu_then_pool(case):
             v, p["conv1"], p["b1"], mode="xla"))(params, x)
         _assert_ties(batched(lambda p, v: kd.conv3x3_bias_relu(
             v, p["conv2"], p["b2"], mode="xla", relu=False))(params, y1))
-    out, vjp = run(lambda p, v: _features_fused(p, v, "interpret"))
-    same, same_vjp = run(lambda p, v: _relu_then_pool(p, v, "interpret"))
-    cpu, cpu_vjp = run(lambda p, v: _relu_then_pool(p, v, "xla"))
-    g = jax.random.normal(ks[5], out.shape)
-    got, ref, ref_cpu = vjp(g), same_vjp(g), cpu_vjp(g)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(same))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(cpu), atol=1e-5)
-    for a, b, c in zip(jax.tree.leaves(got), jax.tree.leaves(ref),
-                       jax.tree.leaves(ref_cpu)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    outs, grads = {}, {}
+    for mode in ("interpret", "xla"):
+        out, vjp = run(lambda p, v: _features(p, v, mode))
+        same, same_vjp = run(lambda p, v: _relu_then_pool(p, v, mode))
+        g = jax.random.normal(ks[5], out.shape)
+        got, ref = vjp(g), same_vjp(g)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(same))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        outs[mode], grads[mode] = out, got
+    np.testing.assert_allclose(np.asarray(outs["interpret"]),
+                               np.asarray(outs["xla"]), atol=1e-5)
+    for a, c in zip(jax.tree.leaves(grads["interpret"]),
+                    jax.tree.leaves(grads["xla"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4)
 
 
@@ -422,32 +408,21 @@ def test_conv3x3_bf16_storage():
 @pytest.mark.kernel_oracle
 @pytest.mark.parametrize("m", [1, 100, 256, 257, 400])
 def test_eval_head_matches_ref_on_tile_tails(m):
-    """Exact correct-count equality across M-tile tails (the count is an
-    integer sum of per-tile integer partials — no tolerance)."""
-    ks = jax.random.split(jax.random.key(3), 4)
-    f, c = 33, 10
-    feats = jax.random.normal(ks[0], (m, f))
-    wmat = jax.random.normal(ks[1], (f, c)) * 0.1
-    bias = jax.random.normal(ks[2], (c,)) * 0.1
-    labels = jax.random.randint(ks[3], (m,), 0, c)
-    got = eval_head(feats, wmat, bias, labels, interpret=True)
-    ref = eval_head_ref(feats, wmat, bias, labels)
+    """The head ``cnn_correct_fast`` counts through (logits, argmax,
+    compare, count) against ``ref.eval_head_ref``, exactly, with a label
+    of -1 (the engine's padding of the tail chunk) on every third row."""
+    from repro.models import cnn_correct_fast, cnn_specs, init_from_specs
+    from repro.models.cnn import _features
+
+    ks = jax.random.split(jax.random.key(3), 3)
+    params = init_from_specs(cnn_specs(image_hw=4, c1=2, c2=3), ks[0])
+    images = jax.random.normal(ks[1], (m, 4, 4, 1))
+    labels = jax.random.randint(ks[2], (m,), 0, 10)
+    labels = jnp.where(jnp.arange(m) % 3 == 2, -1, labels)
+    feats = _features(params, images, "xla")
+    got = cnn_correct_fast(params, images, labels)
+    ref = eval_head_ref(feats, params["dense"], params["b3"], labels)
     assert got.dtype == jnp.int32
-    assert int(got) == int(ref)
-
-
-@pytest.mark.kernel_oracle
-def test_eval_head_bf16_inputs():
-    """bf16 feats/weights: both paths cast to f32 before the identical
-    matmul, so the argmax — and the count — must agree exactly."""
-    ks = jax.random.split(jax.random.key(4), 4)
-    m, f, c = 70, 21, 5
-    feats = jax.random.normal(ks[0], (m, f), jnp.bfloat16)
-    wmat = (jax.random.normal(ks[1], (f, c)) * 0.2).astype(jnp.bfloat16)
-    bias = (jax.random.normal(ks[2], (c,)) * 0.2).astype(jnp.bfloat16)
-    labels = jax.random.randint(ks[3], (m,), 0, c)
-    got = eval_head(feats, wmat, bias, labels, interpret=True)
-    ref = eval_head_ref(feats, wmat, bias, labels)
     assert int(got) == int(ref)
 
 
@@ -556,9 +531,11 @@ def test_dispatch_baseline_aggregates_match_references():
 
 @pytest.mark.kernel_oracle
 def test_dispatch_conv_eval_interpret_matches_xla_branch():
-    """The two train/eval dispatch entries: interpret (XLA's convolution,
-    the eval-head kernel) vs the xla branch (the engine's original
-    im2col conv and eval chain, bit-for-bit)."""
+    """The conv block and the eval count under interpret (XLA's
+    convolution) vs the xla branch (the engine's original im2col conv,
+    bit-for-bit)."""
+    from repro.models import cnn_correct_fast, cnn_specs, init_from_specs
+
     ks = jax.random.split(jax.random.key(10), 3)
     x = jax.random.normal(ks[0], (2, 8, 8, 3))
     w = jax.random.normal(ks[1], (3, 3, 3, 6)) * 0.3
@@ -567,13 +544,12 @@ def test_dispatch_conv_eval_interpret_matches_xla_branch():
         np.asarray(kd.conv3x3_bias_relu(x, w, b, mode="interpret")),
         np.asarray(kd.conv3x3_bias_relu(x, w, b, mode="xla")), atol=1e-5)
 
-    ks = jax.random.split(jax.random.key(11), 4)
-    feats = jax.random.normal(ks[0], (50, 20))
-    wmat = jax.random.normal(ks[1], (20, 10)) * 0.1
-    bias = jax.random.normal(ks[2], (10,)) * 0.1
-    labels = jax.random.randint(ks[3], (50,), 0, 10)
-    assert int(kd.eval_head(feats, wmat, bias, labels, mode="interpret")) \
-        == int(kd.eval_head(feats, wmat, bias, labels, mode="xla"))
+    ks = jax.random.split(jax.random.key(11), 3)
+    params = init_from_specs(cnn_specs(image_hw=8, c1=4, c2=8), ks[0])
+    images = jax.random.normal(ks[1], (50, 8, 8, 1))
+    labels = jax.random.randint(ks[2], (50,), 0, 10)
+    assert int(cnn_correct_fast(params, images, labels, "interpret")) \
+        == int(cnn_correct_fast(params, images, labels, "xla"))
 
 
 # ------------------------------------------------------------ engine parity

@@ -9,9 +9,8 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import hieavg
-from repro.kernels.flash_attention import flash_attention_1h
-from repro.kernels.hieavg_agg import hieavg_agg
-from repro.kernels.ops import flash_attention, fused_edge_aggregate
+from repro.kernels.flash_attention import flash_attention, flash_attention_1h
+from repro.kernels.hieavg_agg import fused_mix_and_update, hieavg_agg
 from repro.kernels.ref import flash_attention_ref, hieavg_agg_ref
 from repro.models.attention import _sdpa
 
@@ -39,7 +38,8 @@ def test_hieavg_agg_matches_ref(n, l, dt, seed):
 
 
 def test_fused_edge_aggregate_matches_core():
-    """ops.fused_edge_aggregate == core hieavg.edge_aggregate end to end."""
+    """``fused_mix_and_update`` with uniform 1/n part weights == core
+    ``hieavg.edge_aggregate`` end to end."""
     n = 5
     stacked = {"a": jax.random.normal(jax.random.key(0), (n, 13, 7)),
                "b": jax.random.normal(jax.random.key(1), (n, 40))}
@@ -53,8 +53,9 @@ def test_fused_edge_aggregate_matches_core():
     for normalize in (False, True):
         agg_ref, h_ref = hieavg.edge_aggregate(stacked, mask, hist,
                                                normalize=normalize)
-        agg_got, h_got = fused_edge_aggregate(stacked, mask, hist,
-                                              normalize=normalize)
+        agg_got, h_got = fused_mix_and_update(
+            stacked, mask, hist, jnp.full((n,), 1.0 / n), 0.9, 0.9,
+            normalize)
         for k in stacked:
             np.testing.assert_allclose(np.asarray(agg_got[k]),
                                        np.asarray(agg_ref[k]), atol=1e-5)

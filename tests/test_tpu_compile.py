@@ -10,14 +10,15 @@ XLA's own convolution: its cases check that it compiles to one with no
 custom call around it, and that a whole train step stays within a
 temporary-memory budget that the 9x im2col patch buffer would break and
 within a bound on its bytes accessed that extra full-size passes of the
-pooling block would break.
+pooling block would break.  The step's SGD update and the eval head are
+plain XLA: their cases check that they compile with no custom call.
 
 The topology is described inside a fixture (never at import), so only the
 worker that runs this file loads the TPU library.
 
 The last case compiles the engine's one-round program itself, at a tiny
-size, and checks that each Pallas call of it lies under one round phase
-(``repro.telemetry.PHASES``): what a device trace's phase metrics read.
+size, and checks that each Pallas call of it lies under one of the two aggregation phases
+(``repro.telemetry``): what a device trace's phase metrics read.
 """
 import dataclasses
 import functools
@@ -29,9 +30,7 @@ import pytest
 
 from repro.kernels import dispatch
 from repro.kernels.coef_agg import coef_agg, coef_agg_pair
-from repro.kernels.eval_head import eval_head
 from repro.kernels.hieavg_agg import hieavg_agg
-from repro.kernels.sgd_update import sgd_update
 
 D, B, HW, C1, C2, NCLS = 25, 32, 28, 32, 64, 10   # DEFAULT widths
 N_EDGES, J = 5, 5
@@ -74,9 +73,6 @@ def _per_edge(fn):
 
 
 CASES = {
-    "sgd_update": lambda: (
-        functools.partial(sgd_update, interpret=False),
-        ((D, L_DENSE), (D, L_DENSE), ())),
     "hieavg_agg": lambda: (
         _per_edge(hieavg_agg),
         ((N_EDGES, J, L_DENSE),) * 3 + ((N_EDGES, J),) * 4),
@@ -85,25 +81,14 @@ CASES = {
     "coef_agg_pair": lambda: (
         _per_edge(coef_agg_pair),
         ((N_EDGES, J, L_DENSE),) * 2 + ((N_EDGES, J),) * 2),
-    "eval_head": lambda: (
-        lambda f, w, b, y: eval_head(f, w, b, y, interpret=False),
-        ((N_TEST, DENSE), (DENSE, NCLS), (NCLS,), (N_TEST,))),
-    # a 3-point sweep vmaps the eval over its points' models
-    "eval_head_3_points": lambda: (
-        jax.vmap(lambda f, w, b, y: eval_head(f, w, b, y, interpret=False),
-                 in_axes=(0, 0, 0, None)),
-        ((3, N_TEST, DENSE), (3, DENSE, NCLS), (3, NCLS), (N_TEST,))),
 }
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, shapes = CASES[name]()
-    # eval_head's fourth operand is the int32 label vector
-    dtypes = [jnp.int32 if name.startswith("eval_head") and i == 3
-              else jnp.float32 for i in range(len(shapes))]
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-            for s, dt in zip(shapes, dtypes)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -180,12 +165,50 @@ def test_train_step_bytes_accessed_bound(train_step):
     assert cost["bytes accessed"] < 8.4e9
 
 
+def _eval(one_chip, points):
+    """The engine's eval of the global model on the 1000 test images at
+    DEFAULT widths; with ``points``, a sweep's eval, vmapped over its
+    points' models with the test set shared."""
+    from repro.fl.engine import eval_accuracy
+    from repro.models import cnn_specs, init_from_specs
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lead = (points,) if points else ()
+    params = jax.eval_shape(
+        lambda: init_from_specs(cnn_specs(), jax.random.key(0)))
+    args = (jax.tree.map(lambda a: spec(lead + a.shape, a.dtype), params),
+            spec((N_TEST, HW, HW, 1)), spec((N_TEST,), jnp.int32))
+    fn = functools.partial(eval_accuracy, kernel_mode="pallas")
+    if points:
+        fn = jax.vmap(fn, in_axes=(0, None, None))
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("name", ["sgd_update", "eval_head",
+                                  "eval_head_3_points"])
+def test_step_and_eval_compile_without_pallas_for_v5e(name, request,
+                                                      one_chip,
+                                                      no_persistent_cache):
+    """The train step (its SGD update inline) and the eval head, one
+    model's and a 3-point sweep's, compile for the chip as plain XLA: no
+    Pallas custom call."""
+    if name == "sgd_update":
+        compiled = request.getfixturevalue("train_step")
+    else:
+        compiled = _eval(one_chip, 3 if name.endswith("3_points") else None)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
 def test_engine_pallas_calls_lie_under_one_phase(one_chip,
                                                  no_persistent_cache):
     """Each Pallas custom call of the one-round ``run_engine_chunk``,
     compiled for the described chip, names exactly one round phase in its
-    ``op_name``.  The lowered text cannot show it: there each kernel sits
-    in a function of its own, whose locations name only the kernel."""
+    ``op_name``, and that phase is one of the two aggregations: train and
+    eval run no Pallas kernel.  The lowered text cannot show it: there
+    each kernel sits in a function of its own, whose locations name only
+    the kernel."""
     from repro import telemetry
     from repro.configs.bhfl_cnn import REDUCED
     from repro.fl import BHFLSimulator, engine
@@ -209,6 +232,7 @@ def test_engine_pallas_calls_lie_under_one_phase(one_chip,
     calls = [phases(ln) for ln in lines
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls and all(len(ph) == 1 for ph in calls), calls
-    assert {ph[0] for ph in calls} == set(telemetry.PHASES)
+    assert {ph[0] for ph in calls} == {telemetry.EDGE_AGG,
+                                       telemetry.GLOBAL_AGG}
     # XLA may merge instructions and their op_names; none names two phases
     assert all(len(phases(ln)) <= 1 for ln in lines)
