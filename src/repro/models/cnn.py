@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .spec import ParamSpec
@@ -130,10 +131,18 @@ def max_pool2x2(x: jnp.ndarray) -> jnp.ndarray:
     Its residual is a 4-bit mask per output, bit ``2i + j`` set where tap
     ``(i, j)`` equals the max.  The forward finds max and mask in one
     variadic reduction, one read of ``x``; the backward reads only the
-    pooled gradient and the mask, counts the set bits elementwise, and
-    writes ``dx``.  ``reduce_max``'s own derivative instead reads ``x``
-    twice more at full size: once to count the ties, as a reduction over
-    the window axes, and once to place the gradient.
+    pooled gradient and the mask: ``dx = hit · share`` over the
+    [..., H/2, 2, W/2, 2, C] view, ``share = g / count`` with ``count``
+    the mask's set bits and ``hit`` its bit at each tap.  That is
+    ``reduce_max``'s derivative written the same way (a boolean times the
+    share), so each compiler makes the same of both: XLA:CPU keeps the
+    product, and a zero takes the sign of ``g``; the TPU compiler turns
+    both into a select, and a zero is +0.  On the TPU no ``dx`` is
+    written: the compiler broadcasts the share (as bfloat16) and the
+    mask over the window and fuses the product into each conv-backward
+    fusion that reads ``dx``.  ``reduce_max``'s own derivative instead
+    reads ``x`` twice more at full size: once to count the ties, as a
+    reduction over the window axes, and once to place the gradient.
 
     A device trace files the backward's ops under this function's frame
     and the forward's under ``_max_pool_fwd``: JAX gives the ops of a
@@ -163,14 +172,17 @@ def _max_pool_fwd(x):
                       pick, (nd - 4, nd - 2))
 
 
+#: Bit ``2i + j`` of a mask, placed at tap ``(i, j)`` of the
+#: [..., H/2, 2, W/2, 2, C] view.
+_TAP_BIT = np.array([[1, 2], [4, 8]], np.uint8).reshape(2, 1, 2, 1)
+
+
 def _max_pool_bwd(mask, g):
-    bits = [((mask >> k) & 1).astype(g.dtype) for k in range(4)]
-    share = g / ((bits[0] + bits[1]) + (bits[2] + bits[3]))
-    d = [bit * share for bit in bits]
-    # stacked, not broadcast over the window: for the conv backward that
-    # consumes dx, the TPU compiler writes a broadcast out at full size
-    rows = [jnp.stack(d[2 * i:2 * i + 2], axis=-2) for i in range(2)]
-    dx = jnp.stack(rows, axis=-4)       # [..., H/2, 2, W/2, 2, C]
+    share = g / lax.population_count(mask).astype(g.dtype)
+    hit = jnp.expand_dims(mask, (-4, -2)) & _TAP_BIT != 0
+    # broadcast over the window, not stacked: the TPU compiler fuses the
+    # product into each conv-backward fusion that reads dx
+    dx = hit.astype(g.dtype) * jnp.expand_dims(share, (-4, -2))
     h2, w2, c = g.shape[-3:]
     return (dx.reshape(g.shape[:-3] + (2 * h2, 2 * w2, c)),)
 

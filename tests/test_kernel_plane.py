@@ -371,6 +371,50 @@ def test_fused_features_pool_before_relu_match_relu_then_pool(case):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4)
 
 
+def _stacked_pool_bwd(mask, g):
+    """``max_pool2x2``'s backward as four tap planes ``bit · share``
+    stacked into the window (the form before the product over the window
+    view); the oracle of ``test_pool_backward_matches_stacked_form``."""
+    bits = [((mask >> k) & 1).astype(g.dtype) for k in range(4)]
+    share = g / ((bits[0] + bits[1]) + (bits[2] + bits[3]))
+    d = [bit * share for bit in bits]
+    rows = [jnp.stack(d[2 * i:2 * i + 2], axis=-2) for i in range(2)]
+    dx = jnp.stack(rows, axis=-4)
+    h2, w2, c = g.shape[-3:]
+    return dx.reshape(g.shape[:-3] + (2 * h2, 2 * w2, c))
+
+
+@pytest.mark.kernel_oracle
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_pool_backward_matches_stacked_form(case):
+    """``max_pool2x2``'s VJP under ``vmap`` over two more leading dims
+    (as the engine's vmap over devices and a sweep's over points run
+    it), against the stacked tap planes applied to the forward's mask:
+    equal to the bit, signed zeros included, with ties and all-zero
+    windows in the coarse cases."""
+    from repro.models.cnn import _max_pool_fwd, max_pool2x2
+
+    ks = jax.random.split(jax.random.key(14), 5)
+    x = jnp.stack([_pool_input(k, case) for k in ks[:4]])
+    x = x.reshape((2, 2) + x.shape[1:])
+    if POOL_CASES[case][1]:
+        _assert_ties(x)
+
+    def outer(fn):
+        return jax.vmap(jax.vmap(fn))
+
+    def new(v, g):
+        return jax.vjp(max_pool2x2, v)[1](g)[0]
+
+    def stacked(v, g):
+        return _stacked_pool_bwd(_max_pool_fwd(v)[1], g)
+
+    g = jax.random.normal(ks[4], x.shape[:-3] + (4, 4, x.shape[-1]))
+    got, ref = outer(new)(x, g), outer(stacked)(x, g)
+    assert np.any(_bits(ref) == _bits(np.float32([-0.0])))
+    np.testing.assert_array_equal(_bits(got), _bits(ref))
+
+
 @pytest.mark.kernel_oracle
 @pytest.mark.parametrize("mode", ["xla", "interpret"])
 @pytest.mark.parametrize("chunk", [16, 100, 250])
